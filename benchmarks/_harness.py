@@ -35,9 +35,12 @@ def run_py_subprocess(script: str, *, label: str, timeout: int = 900) -> Dict:
     """Run ``python -c script`` and parse the JSON object it prints on
     its last stdout line. The child typically sets
     ``--xla_force_host_platform_device_count`` before importing jax to
-    get a placeholder multi-device mesh."""
+    get a placeholder multi-device mesh. It runs pinned to the CPU
+    (``JAX_PLATFORMS=cpu``), so on a host with a chip it never competes
+    with its parent for the device."""
     proc = subprocess.run([sys.executable, "-c", script],
-                          capture_output=True, text=True, timeout=timeout)
+                          capture_output=True, text=True, timeout=timeout,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
     if proc.returncode != 0:
         raise RuntimeError(
             f"{label} subprocess failed:\n{proc.stdout}\n{proc.stderr}")

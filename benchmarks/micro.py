@@ -322,6 +322,7 @@ RING_POOL_ELEMS = 8 * 1237 + 5
 _RING_BENCH_SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import sys, json
 sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np
@@ -502,6 +503,7 @@ OVERLAP_SHAPES = [(771,), (1285,), (1799,), (2313,)]
 _OVERLAP_SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import sys, json, re
 sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np
@@ -544,8 +546,7 @@ with compat_set_mesh(mesh):
 
 # Scan only the shard_map BODY: jaxpr printing may hoist jnp.where into
 # named `_where` closures above the main jaxpr — eqn order is meaningful
-# only from the shard_map call on, where those closures are invoked
-# (`pjit[name=_where ...]` on jax 0.4.x; inline select_n on newer jax).
+# only from the shard_map call on, where those closures are invoked.
 body_at = next(i for i, ln in enumerate(lines) if "shard_map[" in ln)
 lines = lines[body_at:]
 
@@ -788,6 +789,7 @@ GUARD_SHAPES = [(777,), (1281,), (2049,)]
 _GUARD_SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import sys, json, re
 sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp
@@ -1302,6 +1304,7 @@ _PIPE_MIN_SPEEDUP = 1.15
 _PIPE_BITID_SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import sys, json
 sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np

@@ -22,17 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _struct(shape, dtype, like):
-    """ShapeDtypeStruct whose vma matches ``like`` (required when the kernel
-    runs inside a manual shard_map region with check_vma)."""
-    try:
-        vma = jax.typeof(like).vma
-    except Exception:
-        vma = None
-    if vma is not None:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+from repro.kernels import tiling
 
 
 def update_math(master, grads, mom, mask, lr, *, momentum, weight_decay,
@@ -90,8 +80,8 @@ def fused_update(master, grads, momentum_buf, mask, *, lr, momentum,
         grid=(n // blk,),
         in_specs=[one, vec, vec, vec, vec, scale_spec],
         out_specs=(vec, vec),
-        out_shape=(_struct((n,), master.dtype, master),
-                   _struct((n,), momentum_buf.dtype, momentum_buf)),
+        out_shape=(tiling.out_struct((n,), master.dtype, master),
+                   tiling.out_struct((n,), momentum_buf.dtype, momentum_buf)),
         interpret=interpret,
     )(lr_arr, master, grads, momentum_buf, mask, scale)
 

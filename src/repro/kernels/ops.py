@@ -1,15 +1,17 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU these run the compiled kernels (interpret=False). In this CPU
-container they run in interpret mode, which executes the kernel body in
-Python/XLA-CPU — bit-identical semantics, validated against ref.py.
+Each call decides, when it is made (never at import), how the kernel
+runs: compiled (``interpret=False``) when the default backend is a TPU,
+otherwise in Pallas interpret mode, which executes the kernel body on
+XLA:CPU with the same semantics, validated against ref.py.
 
-One CPU-only caveat: interpret mode lowers the kernel grid to a
+One interpret-only caveat: interpret mode lowers the kernel grid to a
 ``while_loop`` whose internal carry cannot carry shard_map's device-varying
 (vma) tags, so *inside a manual shard_map region* the interpret path
 dispatches to the pure-jnp ref instead (same math — the kernels' semantics
-are exactly ref.py, enforced by tests/test_kernels.py). On TPU the real
-kernels run everywhere.
+are exactly ref.py, enforced by tests/test_kernels.py). On TPU the
+compiled kernels run everywhere; ``dispatch_counts`` shows which path
+each call took.
 """
 from __future__ import annotations
 
@@ -24,9 +26,6 @@ from repro.kernels import fused_update as _fu
 from repro.kernels import pool_pack as _pp
 from repro.kernels import ref
 
-# TPU targets run compiled kernels; anything else interprets.
-_INTERPRET = jax.default_backend() != "tpu"
-
 # Python-level dispatch tally: the kernel/ref decision happens here, in
 # python, at call/trace time — so counting it here is faithful. The
 # kernel-bench CI gate reads this to prove the streaming kernels are the
@@ -40,16 +39,17 @@ def _count(name: str, path: str) -> None:
     dispatch_counts[key] = dispatch_counts.get(key, 0) + 1
 
 
+def _interpret() -> bool:
+    """Compiled kernels on a TPU backend; interpret mode anywhere else."""
+    return jax.default_backend() != "tpu"
+
+
 def _needs_ref_fallback(*arrays) -> bool:
-    if not _INTERPRET:
+    """Interpret mode inside a vma-tagged shard_map region (see module
+    docstring). ``None`` operands (optional arguments) are skipped."""
+    if not _interpret():
         return False
-    for a in arrays:
-        try:
-            if jax.typeof(a).vma:
-                return True
-        except Exception:
-            continue
-    return False
+    return any(jax.typeof(a).vma for a in arrays if a is not None)
 
 
 def ring_allreduce(x: jax.Array, axes: Sequence[str],
@@ -64,7 +64,8 @@ def ring_allreduce(x: jax.Array, axes: Sequence[str],
     * compiled TPU — the Pallas RDMA kernel (``kernels.ring_reduce``);
     * CPU/interpret — the ``lax.ppermute`` twin (``ref.ring_allreduce``),
       the kernel's correctness oracle;
-    * new-jax shard_map regions with vma tags (``check_vma=True``) — the
+    * interpret mode in shard_map regions with vma tags
+      (``check_vma=True``) — the
       vma-safe twin (ring reduce-scatter + place-and-psum gather): the
       checker keeps the varying tag on every ppermute result, so the full
       ring cannot leave such a region as a replicated value.
@@ -96,7 +97,7 @@ _RING_ID_AXES = 8
 
 def _ring_one(x: jax.Array, axis: str, wire_dtype,
               collective_id: int = 0) -> jax.Array:
-    if not _INTERPRET:
+    if not _interpret():
         from repro.kernels import ring_reduce
         from repro.parallel.collectives import axis_size
         _count("ring_allreduce", "kernel")
@@ -112,15 +113,19 @@ def _ring_one(x: jax.Array, axis: str, wire_dtype,
 
 def chunk_l1norm(pool: jax.Array, chunk_elems: int) -> jax.Array:
     if _needs_ref_fallback(pool):
+        _count("chunk_l1norm", "ref")
         return ref.chunk_l1norm(pool, chunk_elems)
-    return _cl.chunk_l1norm(pool, chunk_elems, interpret=_INTERPRET)
+    _count("chunk_l1norm", "kernel")
+    return _cl.chunk_l1norm(pool, chunk_elems, interpret=_interpret())
 
 
 def csc_compact(pool: jax.Array, idx: jax.Array,
                 chunk_elems: int) -> jax.Array:
     if _needs_ref_fallback(pool, idx):
+        _count("csc_compact", "ref")
         return ref.csc_compact(pool, idx, chunk_elems)
-    return _cc.csc_compact(pool, idx, chunk_elems, interpret=_INTERPRET)
+    _count("csc_compact", "kernel")
+    return _cc.csc_compact(pool, idx, chunk_elems, interpret=_interpret())
 
 
 def pool_pack(leaves: Sequence[jax.Array], offsets: Tuple[int, ...],
@@ -140,32 +145,34 @@ def pool_pack(leaves: Sequence[jax.Array], offsets: Tuple[int, ...],
     kernel as an ``input_output_aliases`` operand — the packed pool is
     written into the donated buffer and returned as the staging for the
     next step, so steady-state packs allocate nothing pool-sized. A
-    *source*-dtype ``out`` (the legacy ref contract, where staging and
-    wire dtypes differ) still routes to the ref twin, as do empty pools
-    and the shard_map/interpret vma limitation described in the module
-    docstring."""
+    *source*-dtype ``out`` that differs from the wire dtype asks for the
+    ref twin's stage-then-cast contract and takes the ref twin (the
+    trainer never passes one with kernels on: its staging is always the
+    wire dtype), as do empty pools and the shard_map/interpret vma
+    limitation described in the module docstring."""
     wire = jnp.dtype(wire_dtype)
     src = jnp.result_type(*leaves) if leaves else wire
-    wire_staging = out is not None and out.dtype == wire and out.dtype != src
+    wire_staging = out is not None and out.dtype == wire
     assert out is None or wire_staging or out.dtype == src, (
         "staging buffer must be wire- or source-dtype",
         out.dtype, wire, src)
     if not leaves or _needs_ref_fallback(*leaves) or \
-            (out is not None and out.dtype == src):
+            (out is not None and not wire_staging):
         _count("pool_pack", "ref")
         # The ref twin stages in the source dtype; a wire-dtype staging
-        # buffer (the kernel aliasing contract) cannot seed it — drop the
-        # donation for this (fallback-only) call and hand the pool back
-        # as the next step's wire staging so the threading stays typed.
+        # buffer (the kernel aliasing contract) seeds it only when the
+        # two dtypes agree — otherwise drop the donation for this call
+        # and hand the pool back as the next step's wire staging so the
+        # threading stays typed.
+        seed = out if (out is None or out.dtype == src) else None
         pool, norms, staging = ref.pool_pack(
-            leaves, offsets, pool_size, chunk_elems, wire_dtype,
-            out=None if wire_staging else out)
+            leaves, offsets, pool_size, chunk_elems, wire_dtype, out=seed)
         return pool, norms, (pool if wire_staging else staging)
     _count("pool_pack", "kernel")
     pool, norms = _pp.pool_pack(
         tuple(leaves), tuple(offsets), tuple(sizes), pool_size,
         chunk_elems, wire.name, tile_elems=tile_elems,
-        staging=out if wire_staging else None, interpret=_INTERPRET)
+        staging=out, interpret=_interpret())
     return pool, norms, (pool if wire_staging else None)
 
 
@@ -181,8 +188,8 @@ def update_unpack(master, grads, momentum_buf, mask,
     ``ratios`` passes the per-tensor LARS vector for in-kernel expansion
     (no pool-sized scale buffer); ``scale`` remains the expanded
     per-element form for the oracle/fallback paths."""
-    if not sizes or _needs_ref_fallback(master, grads, momentum_buf, mask,
-                                        scale, ratios):
+    if _needs_ref_fallback(master, grads, momentum_buf, mask, scale,
+                           ratios):
         _count("update_unpack", "ref")
         return ref.pool_unpack_update(
             master, grads, momentum_buf, mask, offsets, sizes, lr=lr,
@@ -192,7 +199,7 @@ def update_unpack(master, grads, momentum_buf, mask,
     return _fu.update_unpack(
         master, grads, momentum_buf, mask, tuple(offsets), tuple(sizes),
         lr=lr, momentum=momentum, weight_decay=weight_decay, scale=scale,
-        ratios=ratios, tile_elems=tile_elems, interpret=_INTERPRET)
+        ratios=ratios, tile_elems=tile_elems, interpret=_interpret())
 
 
 # Back-compat name for the update-side entry point.
@@ -203,9 +210,11 @@ def fused_update(master, grads, momentum_buf, mask, *, lr, momentum,
                  weight_decay, scale: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, jax.Array]:
     if _needs_ref_fallback(master, grads, momentum_buf, mask):
+        _count("fused_update", "ref")
         return ref.fused_update(master, grads, momentum_buf, mask, lr=lr,
                                 momentum=momentum,
                                 weight_decay=weight_decay, scale=scale)
+    _count("fused_update", "kernel")
     return _fu.fused_update(master, grads, momentum_buf, mask, lr=lr,
                             momentum=momentum, weight_decay=weight_decay,
-                            scale=scale, interpret=_INTERPRET)
+                            scale=scale, interpret=_interpret())

@@ -39,8 +39,9 @@ the bandwidth-optimal ring of the paper's Fig 7a. Mechanics:
   drain serially (start→wait per copy); overlapping the next HBM load
   behind the in-flight RDMA is part of the on-TPU validation item in
   ROADMAP.
-* Neighbor exchanges use ``pltpu.make_async_remote_copy`` with logical
-  device ids along the ring axis. Flow control is **credit-based**, not
+* Neighbor exchanges use ``pltpu.make_async_remote_copy`` addressed by
+  mesh coordinates (the ring axis's neighbor position; every other mesh
+  axis at this device's own coordinate). Flow control is **credit-based**, not
   barrier-based: after draining sub-tile k from its recv slot, a rank
   signals a credit to its LEFT neighbor (the sender); before writing
   sub-tile k (k >= 2) into the RIGHT neighbor's slot ``k % 2``, a rank
@@ -60,8 +61,9 @@ The pure-jax ``lax.ppermute`` twin (``ref.ring_allreduce``) is the
 correctness oracle and the CPU/interpret execution path: remote DMA has
 no multi-device interpret mode, so ``ops.ring_allreduce`` dispatches to
 the twin everywhere except compiled TPU (see ops docstring for the
-vma-safe variant used under new-jax ``check_vma`` regions). On-TPU
-validation is tracked in ROADMAP alongside the streaming pool kernels.
+vma-safe variant used under ``check_vma`` regions). ``chip_smoke.py
+--chips 4`` checks the compiled kernel against a host sum and against
+the twin on a four-chip host.
 """
 from __future__ import annotations
 
@@ -75,10 +77,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tiling
 
-# Renamed across jax versions (TPUCompilerParams -> CompilerParams); the
-# kernel only touches it on the compiled-TPU path.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams", None)
+# Tile granule of the compiled ring, in elements: one (32, 128) tile, the
+# Mosaic tile of the narrowest (int8) dtype the ring carries.
+RING_ALIGN = 32 * tiling.LANES
 
 
 def ring_segment_bounds(n_elems: int, n_ranks: int,
@@ -151,19 +152,24 @@ def plan(n_elems: int, n_ranks: int, wire_dtype,
 
 
 def _kernel(ids_ref, x_ref, out_ref, send_buf, recv_buf, stage, seed_buf,
-            send_sems, recv_sems, copy_sems, credit_sem, *, n: int,
-            seg: int, tile: int, wire, accum):
+            send_sems, recv_sems, copy_sems, credit_sem, *, axis: str,
+            n: int, seg: int, tile: int, wire, accum):
     """One rank's full 2(N-1)-step ring. ``ids_ref`` holds
-    (my_id, right_id, left_id) in SMEM; ``x_ref``/``out_ref`` are the
-    padded (n*seg,) source-dtype input and f32 accumulator in HBM."""
+    (me, right, left) — positions along the ring ``axis`` — in SMEM;
+    ``x_ref``/``out_ref`` are the padded (n*seg,) source-dtype input and
+    f32 accumulator in HBM, both viewed as (rows, 128); ``seg`` and
+    ``tile`` count rows. Peers are addressed by mesh coordinates: the
+    ring axis set to the neighbor's position, every other mesh axis left
+    at this device's own coordinate, so a ring along ``data`` stays
+    inside its row of a multi-axis mesh."""
     me = ids_ref[0]
-    right = ids_ref[1]
-    left = ids_ref[2]
+    right = {axis: ids_ref[1]}
+    left = {axis: ids_ref[2]}
     n_tiles = seg // tile
     integer_wire = jnp.issubdtype(jnp.dtype(wire), jnp.integer)
 
     def tile_ds(base, j):
-        return pl.ds(base + j * tile, tile)
+        return pl.ds(pl.multiple_of(base + j * tile, tile), tile)
 
     def requant(vals):
         """f32 accumulator values -> the wire grid (the requant half of
@@ -184,9 +190,18 @@ def _kernel(ids_ref, x_ref, out_ref, send_buf, recv_buf, stage, seed_buf,
             dst_ref=recv_buf.at[slot],
             send_sem=send_sems.at[slot],
             recv_sem=recv_sems.at[slot],
-            device_id=(right,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id=right,
+            device_id_type=pltpu.DeviceIdType.MESH,
         )
+
+    # Start-up handshake with both neighbors: no peer may write into
+    # this device's recv slots or signal its credit semaphore before
+    # this kernel owns them.
+    barrier = pltpu.get_barrier_semaphore()
+    for peer in (left, right):
+        pltpu.semaphore_signal(barrier, 1, device_id=peer,
+                               device_id_type=pltpu.DeviceIdType.MESH)
+    pltpu.semaphore_wait(barrier, 2)
 
     # Seed the f32 accumulator from the input in its ORIGINAL dtype —
     # local contributions are never wire-rounded, exactly like the ref
@@ -194,15 +209,15 @@ def _kernel(ids_ref, x_ref, out_ref, send_buf, recv_buf, stage, seed_buf,
     # Staging goes through seed_buf — OUR buffer, which no neighbor ever
     # writes — so a fast left neighbor racing ahead into the ring may
     # land its first sub-tiles in recv_buf while we are still seeding
-    # without corrupting anything; no start-up barrier is needed.
+    # without corrupting anything.
     def seed_tile(k, _):
-        cp = pltpu.make_async_copy(x_ref.at[pl.ds(k * tile, tile)],
+        cp = pltpu.make_async_copy(x_ref.at[tile_ds(0, k)],
                                    seed_buf.at[...], copy_sems.at[0])
         cp.start()
         cp.wait()
         stage[0] = seed_buf[...].astype(accum)
         out = pltpu.make_async_copy(stage.at[0],
-                                    out_ref.at[pl.ds(k * tile, tile)],
+                                    out_ref.at[tile_ds(0, k)],
                                     copy_sems.at[0])
         out.start()
         out.wait()
@@ -254,8 +269,8 @@ def _kernel(ids_ref, x_ref, out_ref, send_buf, recv_buf, stage, seed_buf,
             # Drained: our LEFT neighbor (the sender into this slot) may
             # reuse the slot for its sub-tile k+2.
             pltpu.semaphore_signal(
-                credit_sem, inc=1, device_id=(left,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL)
+                credit_sem, inc=1, device_id=left,
+                device_id_type=pltpu.DeviceIdType.MESH)
             return _
 
         jax.lax.fori_loop(0, n_tiles, body, None)
@@ -295,6 +310,10 @@ def _kernel(ids_ref, x_ref, out_ref, send_buf, recv_buf, stage, seed_buf,
         return _
 
     jax.lax.fori_loop(0, n - 1, ag_step, None)
+    # The right neighbor's credits for the last two sub-tiles are never
+    # consumed by a send: take them, so the semaphore leaves at zero and
+    # no signal lands after this kernel has exited.
+    pltpu.semaphore_wait(credit_sem, min(2, 2 * (n - 1) * n_tiles))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -305,8 +324,9 @@ def ring_allreduce(x: jax.Array, axis_name: str, axis_size: int, *,
     """Compiled-TPU ring allreduce of the 1-D ``x`` over ``axis_name``.
 
     Must be called inside the manual shard_map region that owns
-    ``axis_name`` (device ids are logical positions along that single
-    axis). ``collective_id`` must be distinct for every ring that can be
+    ``axis_name``; peers are addressed by mesh coordinates (see
+    ``_kernel``), so the ring may run along one axis of a multi-axis
+    mesh. ``collective_id`` must be distinct for every ring that can be
     live in the same compiled program AND identical across hosts for the
     same logical ring — GradientFlow stamps the bucket index through
     ``ops.ring_allreduce`` (host-invariant by construction); two
@@ -322,33 +342,42 @@ def ring_allreduce(x: jax.Array, axis_name: str, axis_size: int, *,
     wire = jnp.dtype(wire_dtype) if wire_dtype is not None else x.dtype
     accum = jnp.float32
     p = plan(x.shape[0], n, wire, accum, tile_elems, src_dtype=x.dtype)
-    seg, tile = p["seg_elems"], p["tile_elems"]
+    # Every buffer is (rows, 128): whole (32, 128) tiles of the narrowest
+    # dtype the ring carries (int8 words), so every row slice of an HBM
+    # operand and every slot of a VMEM buffer sits on Mosaic's tile grid.
+    # Only segments shorter than one such tile grow here.
+    tile = tiling.round_up(p["tile_elems"], RING_ALIGN)
+    seg = tiling.round_up(p["seg_elems"], tile)
     # The input rides in its ORIGINAL dtype: local contributions reach
     # the f32 accumulator unrounded (matching the ref twin); only the
     # in-flight segments are cast to the wire dtype inside the kernel.
     pad = seg * n - x.shape[0]
     xp = x if not pad else jnp.concatenate(
         [x, jnp.zeros((pad,), x.dtype)])
+    lanes = tiling.LANES
+    tr = tile // lanes
     me = jax.lax.axis_index(axis_name)
     ids = jnp.stack([me, (me + 1) % n, (me - 1) % n]).astype(jnp.int32)
-    kern = functools.partial(_kernel, n=n, seg=seg, tile=tile, wire=wire,
+    kern = functools.partial(_kernel, axis=axis_name, n=n,
+                             seg=seg // lanes, tile=tr, wire=wire,
                              accum=accum)
     out = pl.pallas_call(
         kern,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct((seg * n,), accum),
-        scratch_shapes=[pltpu.VMEM((2, tile), wire),    # send slots
-                        pltpu.VMEM((2, tile), wire),    # recv slots
-                        pltpu.VMEM((2, tile), accum),   # f32 staging
-                        pltpu.VMEM((tile,), x.dtype),   # seed buffer
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((seg * n // lanes, lanes), accum),
+        scratch_shapes=[pltpu.VMEM((2, tr, lanes), wire),    # send slots
+                        pltpu.VMEM((2, tr, lanes), wire),    # recv slots
+                        pltpu.VMEM((2, tr, lanes), accum),   # f32 staging
+                        pltpu.VMEM((tr, lanes), x.dtype),    # seed buffer
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,)),
-                        pltpu.SemaphoreType.REGULAR],   # drain credits
-        compiler_params=_COMPILER_PARAMS(
+                        pltpu.SemaphoreType.REGULAR],        # credits
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=False,
-    )(ids, xp)
+    )(ids, xp.reshape(-1, lanes))
+    out = out.reshape(-1)
     return out[:x.shape[0]].astype(out_dtype)

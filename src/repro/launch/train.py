@@ -11,13 +11,13 @@ a single CPU device (reduced configs; examples/) to the production mesh
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import os
 import time
 from typing import Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_arch, get_smoke
@@ -31,6 +31,7 @@ from repro.launch.mesh import make_mesh
 from repro.launch.trainer import Trainer
 from repro.runtime.fault_tolerance import SupervisorConfig, TrainSupervisor
 from repro.parallel.collectives import compat_set_mesh
+from repro.parallel.topology import REGISTRY
 
 
 class ThroughputMeter:
@@ -75,6 +76,7 @@ def build(args):
         chunk_elems=args.chunk_elems, sparsity=args.sparsity,
         momentum=args.momentum, warmup_steps=args.csc_warmup,
         warmup_stages=4, use_kernels=args.use_kernels,
+        collective_algo=args.collective_algo,
         wire_format=args.wire_format,
         error_feedback=not args.no_error_feedback)
     opt = OptimizerConfig(
@@ -109,6 +111,10 @@ def _parser() -> argparse.ArgumentParser:
     p.add_argument("--momentum", type=float, default=0.9)
     p.add_argument("--attn-chunk", type=int, default=0)
     p.add_argument("--use-kernels", action="store_true")
+    p.add_argument("--collective-algo", default="auto",
+                   choices=sorted(REGISTRY) + ["auto"],
+                   help="data-parallel reduction per bucket; 'auto' "
+                        "picks from the cost model")
     p.add_argument("--wire-format", default="native",
                    choices=["native", "int8", "fp8_e4m3"],
                    help="low-bit wire with per-chunk scales; 'native' "
@@ -128,8 +134,117 @@ def _parser() -> argparse.ArgumentParser:
     return p
 
 
+def configure_compile_cache() -> str:
+    """Persistent compilation cache, one rule for every entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here. Otherwise the cache lives at ONE fixed path in
+    the checkout, ``<checkout>/.jax_cache`` (git ignores it): the path is
+    part of the cache key, so a path that moved between runs would never
+    hit. Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def window_sharding(trainer: Trainer, batch) -> object:
+    """Shardings of a window's stacked batch: the leading scan axis
+    replicated, the batch dim split over the data axes exactly as the
+    step's ``in_specs`` expect (``Trainer.batch_pspec``)."""
+    return jax.tree_util.tree_map(
+        lambda spec: NamedSharding(trainer.mesh, P(None, *spec)),
+        trainer.batch_pspec(batch),
+        is_leaf=lambda x: isinstance(x, P))
+
+
+class WindowLoop:
+    """The compile-once training loop's ``window_fn`` for
+    ``TrainSupervisor.run_windows``: one compiled program per (CSC stage,
+    window length), batches fetched BY STEP INDEX (a supervisor replay
+    re-reads exactly the batches the failed attempt saw), stacked on the
+    leading scan axis and placed with the data sharding, and ONE host
+    sync per window (the stacked [length] losses).
+
+    Stage boundaries are snapped to the window grid: no K-step window
+    ever straddles a sparsity stage, so each stage costs exactly one
+    compiled window executable (snapping can shadow a warm-up stage
+    entirely — those are never built). ``lower_seconds`` and
+    ``compile_seconds`` record each ahead-of-time trace+lowering and
+    compile (the part a persistent-cache hit skips), ``window_seconds``
+    each window's wall time after its compile."""
+
+    def __init__(self, trainer: Trainer, pipe: DataPipeline, window: int,
+                 log_every: int = 0):
+        self.trainer = trainer
+        self.pipe = pipe
+        self.window = max(window, 1)
+        self.stages = snap_stages_to_window(trainer.gf.stages, self.window)
+        self.firsts = stage_first_steps(self.stages)
+        self.log_every = log_every
+        self.executables = {}
+        self.lower_seconds = {}
+        self.compile_seconds = {}
+        self.window_seconds = []
+        self.losses = []
+        self.meter = ThroughputMeter(trainer.cfg.global_batch
+                                     * trainer.cfg.seq_len)
+
+    def executable(self, stage, state, batches):
+        key = (stage.index, jax.tree_util.tree_leaves(batches)[0].shape[0])
+        if key not in self.executables:
+            t0 = time.perf_counter()
+            lowered = self.trainer.build_train_window(
+                self.window, stage=stage).lower(state, batches)
+            t1 = time.perf_counter()
+            self.executables[key] = lowered.compile()
+            self.lower_seconds[key] = t1 - t0
+            self.compile_seconds[key] = time.perf_counter() - t1
+        return self.executables[key]
+
+    def stage_at(self, step: int):
+        return stage_at(self.stages, step, self.firsts)
+
+    def batches(self, step: int, length: int):
+        """The window's batches, stacked and placed with the data
+        sharding."""
+        batches = [self.pipe.next_at(step + i) for i in range(length)]
+        return jax.device_put(
+            jax.tree_util.tree_map(lambda *xs: np.stack(xs), *batches),
+            window_sharding(self.trainer, batches[0]))
+
+    def __call__(self, step: int, length: int, state):
+        stage = self.stage_at(step)
+        stacked = self.batches(step, length)
+        exe = self.executable(stage, state, stacked)
+        t0 = time.perf_counter()
+        state, metrics = exe(state, stacked)
+        win_losses = np.asarray(metrics["loss"], np.float32)
+        self.window_seconds.append(time.perf_counter() - t0)
+        self.losses.extend(float(x) for x in win_losses)
+        self.meter.note(length)
+        due = [s for s in range(step, step + length)
+               if self.log_every and s % self.log_every == 0]
+        if due:
+            s = due[-1]
+            tok_s = self.meter.rate()
+            tail = f"({tok_s:,.0f} tok/s)" if tok_s is not None \
+                else "(compiling)"
+            print(f"step {s:5d} stage {stage.index} "
+                  f"sparsity {stage.sparsity:.2f} "
+                  f"loss {win_losses[s - step]:.4f} {tail}")
+        return state
+
+
 def main(argv=None):
     args = _parser().parse_args(argv)
+    configure_compile_cache()
 
     trainer, cfg, mesh = build(args)
     data = SyntheticLM(cfg.model.vocab_size, seed=args.seed,
@@ -146,48 +261,9 @@ def main(argv=None):
     K = max(cfg.window_steps, 1)
     with compat_set_mesh(mesh):
         state = trainer.init_state(jax.random.PRNGKey(args.seed))
-        # Stage boundaries snapped to the window grid: no K-step window
-        # ever straddles a sparsity stage, so each stage costs exactly
-        # one compiled window executable (snapping can shadow a warm-up
-        # stage entirely — those are never built).
-        stages = snap_stages_to_window(trainer.gf.stages, K)
-        firsts = stage_first_steps(stages)
-        windows_by_stage = {}
-
-        def window_exe(stage):
-            if stage.index not in windows_by_stage:
-                windows_by_stage[stage.index] = \
-                    trainer.build_train_window(K, stage=stage)
-            return windows_by_stage[stage.index]
-
         t_wall = time.time()
-        meter = ThroughputMeter(cfg.global_batch * cfg.seq_len)
-        losses = []
-
-        def window_fn(step, length, state):
-            stage = stage_at(stages, step, firsts)
-            # Batches fetched BY STEP INDEX (not a free-running cursor):
-            # a supervisor replay re-reads exactly the batches the failed
-            # attempt saw, then stacked on the leading scan axis.
-            batches = [pipe.next_at(step + i) for i in range(length)]
-            stacked = jax.device_put(
-                jax.tree_util.tree_map(lambda *xs: np.stack(xs), *batches))
-            state, metrics = window_exe(stage)(state, stacked)
-            # ONE host sync per window: the stacked [length] losses.
-            win_losses = np.asarray(metrics["loss"], np.float32)
-            losses.extend(float(x) for x in win_losses)
-            meter.note(length)
-            due = [s for s in range(step, step + length)
-                   if s % args.log_every == 0]
-            if due:
-                s = due[-1]
-                tok_s = meter.rate()
-                tail = f"({tok_s:,.0f} tok/s)" if tok_s is not None \
-                    else "(compiling)"
-                print(f"step {s:5d} stage {stage.index} "
-                      f"sparsity {stage.sparsity:.2f} "
-                      f"loss {win_losses[s - step]:.4f} {tail}")
-            return state
+        loop = WindowLoop(trainer, pipe, K, log_every=args.log_every)
+        losses = loop.losses
 
         # `is not None`, not truthiness: a checkpoint saved at step 0 is
         # a real checkpoint and must restore (latest_step() is None only
@@ -203,7 +279,7 @@ def main(argv=None):
                   f"--steps {args.steps}")
             return losses
         pipe.start(start)
-        state = sup.run_windows(state, start, args.steps, window_fn, K,
+        state = sup.run_windows(state, start, args.steps, loop, K,
                                 on_restore=pipe.skip_to)
         pipe.stop()
         print(f"done: final loss {losses[-1]:.4f} "

@@ -23,16 +23,9 @@ Distribution architecture (see DESIGN.md §3.1):
        optimizer updates the f32 master; optimizer + GradientFlow state
        is thereby sharded over the model axis (ZeRO-style) for free.
 
-The sibling-region split (previously the update ran in a shard_map NESTED
-inside the fwd/bwd region) is what makes the data-axis collectives legal
-on jax<0.5: the legacy shard_map partitioner rejects any all-reduce over
-outer-manual axes issued from inside a nested manual subgroup ("Manual
-all-reduce across devices that belong to different manual subgroups"),
-and all-gather/ppermute over those axes hard-crash its SPMD partitioner.
-In one flat manual region over (data..., model) the same psums/ppermutes
-are the ordinary subgroup case both jax generations accept — which
-un-xfails the two nested-manual trainer tests (see tests/
-test_distributed.py history).
+The update runs in a SIBLING region, not nested inside the fwd/bwd
+region, so the data-axis psums/ppermutes are issued from one flat manual
+region over (data..., model).
 
 The reduce step dispatches on ``GradientFlowConfig.collective_algo``
 through the topology registry: ``flat``/``two_level``/``tree`` bottom out
@@ -661,7 +654,7 @@ class Trainer:
             """Grad pytree → local 1-D pool (runs where leaf shapes are
             local: directly in the fwd region when model is unsharded,
             else inside the nested pack shard_map below — pure local
-            compute, no collectives, so both jax generations accept it).
+            compute, no collectives).
 
             ``st`` threads the previous step's staging buffer
             (``pack_into`` donation: the pack writes fully in place);
@@ -730,14 +723,22 @@ class Trainer:
             pk_args = (grads,)
             if staging_on:
                 pk_args += (staging_in[0],)
-            if self.model_size > 1:
+            # A model-sharded pool packs in a nested model-manual region;
+            # so does any kernel pack, because a Pallas kernel cannot sit
+            # in a region with an auto axis (even one of size 1). The
+            # nested region re-lists the data axes: under a concrete mesh
+            # context (``set_mesh``) jax lowers it against that mesh, and
+            # only axes listed here then count as manual.
+            if self.model_size > 1 or (self.gf_cfg.use_kernels and
+                                       "model" in self.mesh.axis_names):
                 n_out = len(pk_args) + (1 if census_on else 0)
                 pk_in = (self.param_pspecs,) + \
                     ((pool_spec,) if staging_on else ())
                 outs = compat_shard_map(
-                    pack_local, legacy_mesh=self.mesh,
+                    pack_local,
                     in_specs=pk_in, out_specs=(pool_spec,) * n_out,
-                    axis_names={"model"}, check_vma=False)(*pk_args)
+                    axis_names={"model"} | manual_axes,
+                    check_vma=False)(*pk_args)
             else:
                 outs = pack_local(*pk_args)
             if self.data_axes:
@@ -890,10 +891,7 @@ class Trainer:
         TrainState as the (donated) carry, so a whole window runs with a
         single dispatch and a single host sync.
 
-        The scan wraps the shard_map'd step from the OUTSIDE — the legal
-        direction on both jax generations (a scan *inside* a
-        manual-subgroup region at data>1 × model>1 crashes the jax<0.5
-        SPMD partitioner; see tests/test_distributed.py). Batches arrive
+        The scan wraps the shard_map'd step from the OUTSIDE. Batches arrive
         stacked on a leading scan axis (length ``window_steps``, or
         shorter for a tail window — jit re-specializes per length, so
         keep full windows on the hot path) and per-step metrics return

@@ -13,109 +13,60 @@ the shrunken shard across the slow links, then gather back out.
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Optional, Sequence, Set, Tuple
+from typing import Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
 
 
-# -- jax version shims -------------------------------------------------------
-#
-# The repo targets the current jax API (jax.shard_map, sharding.set_mesh,
-# lax.pcast, lax.axis_size); containers pinned to jax 0.4.37 lack all four.
-# These helpers present the NEW api surface and translate to the legacy
-# equivalents when needed, so trainer/tests/examples are written once:
-#
-#   new jax                       0.4.37 translation
-#   jax.shard_map(axis_names=M)   experimental.shard_map(auto=mesh-M)
-#   check_vma=...                 check_rep=False (the vma checker does not
-#                                 exist; the legacy rep checker rejects
-#                                 valid programs the vma system accepts, so
-#                                 it is disabled rather than approximated)
-#   sharding.set_mesh(mesh)       `with mesh:` (Mesh has been a context
-#                                 manager since the pjit era)
-#   lax.pcast(x, a, 'varying')    identity (no vma type system to tag)
-
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
+# -- shard_map / mesh helpers (jax >= 0.9) ----------------------------------
 
 
 def compat_shard_map(f, *, mesh=None, in_specs, out_specs,
                      axis_names: Optional[Set[str]] = None,
-                     check_vma: bool = True, legacy_mesh=None):
-    """jax.shard_map across jax versions. ``axis_names`` is the NEW-style
-    set of manual axes (None = all mesh axes manual).
-
-    ``mesh=None`` means "resolve from context" on new jax (e.g. a nested
-    shard_map inside a manual region). Old shard_map has no context
-    lookup, so callers that rely on it must supply ``legacy_mesh`` — used
-    ONLY on the legacy path, keeping the new-jax call identical."""
-    if _HAS_NEW_SHARD_MAP:
-        kwargs: dict = {}
-        if mesh is not None:
-            kwargs["mesh"] = mesh
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=check_vma, **kwargs)
-    from jax.experimental.shard_map import shard_map as _legacy
-    mesh = mesh if mesh is not None else legacy_mesh
-    assert mesh is not None, (
-        "jax<0.5 shard_map needs an explicit mesh (no context lookup)")
-    auto = frozenset()
+                     check_vma: bool = True):
+    """``jax.shard_map`` with ``axis_names`` the set of manual axes (None =
+    all mesh axes manual) and ``mesh=None`` meaning "resolve from
+    context" (e.g. a nested shard_map inside a manual region)."""
+    kwargs: dict = {}
+    if mesh is not None:
+        kwargs["mesh"] = mesh
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   auto=auto, check_rep=False)
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma, **kwargs)
 
 
 def compat_set_mesh(mesh) -> contextlib.AbstractContextManager:
-    """``with compat_set_mesh(mesh):`` — sharding.set_mesh where it exists,
-    falling back to use_mesh, then to the Mesh context manager."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh  # 0.4.x: Mesh is itself a context manager
+    """``with compat_set_mesh(mesh):`` — ``jax.sharding.set_mesh``."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def compat_pvary(x: jax.Array, axes: Sequence[str]) -> jax.Array:
-    """Tag ``x`` as varying over manual ``axes`` (new vma type system);
-    identity on jax versions without pcast/pvary, whose shard_map has no
-    varying-axes tags to satisfy."""
-    if hasattr(jax.lax, "pcast"):
-        for a in axes:
-            x = jax.lax.pcast(x, a, to="varying")
-    elif hasattr(jax.lax, "pvary"):
-        for a in axes:
-            x = jax.lax.pvary(x, a)
+    """Tag ``x`` as varying over the manual ``axes``. Only the axes not
+    already in ``x``'s vma set are cast: ``pcast`` rejects a
+    varying -> varying cast of an axis the value already varies over."""
+    missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    if missing:
+        x = jax.lax.pcast(x, missing, to="varying")
     return x
 
 
 def compat_make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """jax.make_mesh, passing axis_types only where the API has it."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def compat_abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """jax.sharding.AbstractMesh across versions: new jax takes
-    (axis_sizes, axis_names); 0.4.x takes one ((name, size), ...) tuple."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    """``jax.sharding.AbstractMesh(axis_sizes, axis_names)``."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 def _one_axis_size(axis: str) -> int:
-    """Static size of a manual axis, across jax versions: lax.axis_size is
-    recent; psum of a Python scalar has always constant-folded to the axis
-    size (the classic ``psum(1, axis)`` idiom)."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis))
-    return int(jax.lax.psum(1, axis))
+    """Static size of one manual axis."""
+    return int(jax.lax.axis_size(axis))
 
 
 def axis_size(axes: Sequence[str]) -> int:

@@ -259,8 +259,8 @@ class GuardLane:
     def _run_windows(self, sm, params, opt, gfstate, scaler, num_steps,
                      window, by_step) -> List[dict]:
         """The compile-once lane: ``lax.scan`` over the shard_mapped
-        guarded body (scan OUTSIDE the manual region — the placement
-        both jax generations accept), the (params, opt, gf, scaler)
+        guarded body (scan OUTSIDE the manual region, as the trainer's
+        windows place it), the (params, opt, gf, scaler)
         carry threaded through the scan, and per-step state snapshots
         returned STACKED so the host syncs once per window yet still
         reconstructs the exact per-step record stream — including the

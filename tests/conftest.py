@@ -19,7 +19,7 @@ def run_multi_device(body: str, devices: int = 8, timeout: int = 900):
     (the main pytest process must keep seeing the single real device).
 
     The prelude provides jax/jnp/np, PartitionSpec ``P``, NamedSharding,
-    the collectives compat shims, ``N`` (= devices), and the ``smap``
+    the collectives helpers, ``N`` (= devices), and the ``smap``
     shorthand over ``compat_shard_map``. Shared by test_distributed /
     test_topology / test_ring_reduce — keep harness fixes here, in ONE
     place (benchmarks/micro.py carries its own inline variant because it
@@ -40,8 +40,11 @@ def run_multi_device(body: str, devices: int = 8, timeout: int = 900):
             return compat_shard_map(f, mesh=mesh, in_specs=in_specs,
                                     out_specs=out_specs, axis_names=axes)
     """) + textwrap.dedent(body)
+    # Pinned to the CPU: on a host with a chip the child never competes
+    # with this process for the device.
     proc = subprocess.run([sys.executable, "-c", script],
-                          capture_output=True, text=True, timeout=timeout)
+                          capture_output=True, text=True, timeout=timeout,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, (
         f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}")
     return proc.stdout

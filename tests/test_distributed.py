@@ -1,24 +1,10 @@
 """Multi-device correctness (8 placeholder CPU devices via subprocess —
 the main pytest process must keep seeing the single real device).
 
-History of the (previously xfailed) model_size>1 trainer tests below:
-jax<=0.4.x's legacy shard_map partitioner rejected the psum over the
-outer data axes issued from inside the nested model-manual update region
-("Manual all-reduce across devices that belong to different manual
-subgroups"). The overlap-engine restructure fixed that: the reduce+update
-now runs in a SIBLING fully-manual (data+model) shard_map — a single-level
-manual region where the same data-axis collectives are the ordinary
-subgroup case both jax generations accept (see launch/trainer.py). One
-orthogonal jax-0.4.x limitation remains, pinned down to its exact failing
-primitive: ``lax.scan`` (any while loop — forward alone suffices, no
-collective needed) inside a manual-SUBGROUP region (manual data, auto
-model) with BOTH data>1 and model>1 hard-crashes old XLA's SPMD
-partitioner (``hlo_sharding_util.cc:2750 Check failed:
-sharding.IsManualSubgroup()``) — previously masked because the psum
-rejection errored out first. The tests therefore switch
-``scan_layers`` off on jax<0.5 ONLY (their property — TP sharding +
-pool-space update are numerically transparent across meshes — is
-scan-independent); on newer jax they keep the full scan+TP+DP coverage.
+The model_size>1 trainer tests run the reduce+update in a SIBLING fully
+manual (data+model) shard_map (see launch/trainer.py) with the layer scan
+on: TP sharding + the pool-space update must be numerically transparent
+across meshes.
 """
 import pytest
 
@@ -93,9 +79,7 @@ def test_csc_cross_shard_selection_agrees_and_reduces():
 def test_trainer_2x2_mesh_modes_match_single_device():
     """Dense/lazy/CSC on a 2x2 (data x model) mesh must reproduce the
     1-device trajectory: TP sharding and the sibling-region update are
-    numerically transparent. Un-xfailed by the overlap-engine restructure
-    (scan_layers switches off on jax<0.5 only, dodging the remaining
-    old-XLA scan-in-subgroup partitioner crash — see module docstring)."""
+    numerically transparent."""
     out = run_multi_device("""
         from repro.configs import get_smoke
         from repro.configs.base import (GradientFlowConfig, OptimizerConfig,
@@ -103,12 +87,6 @@ def test_trainer_2x2_mesh_modes_match_single_device():
         from repro.data.synthetic import SyntheticLM
         from repro.launch.mesh import make_mesh
         from repro.launch.trainer import Trainer
-
-        # scan_layers only where the partitioner survives it: old XLA
-        # crashes on scan in a manual-subgroup region at data>1 x model>1
-        # (module docstring); new jax keeps the full scan+TP+DP coverage.
-        scan = tuple(int(x) for x in
-                     jax.__version__.split(".")[:2]) >= (0, 5)
 
         def run(mesh_shape, mode):
             model_cfg, rules = get_smoke("qwen3-32b")
@@ -121,7 +99,7 @@ def test_trainer_2x2_mesh_modes_match_single_device():
                                   warmup_steps=1, total_steps=20,
                                   schedule="constant"),
                               seq_len=32, global_batch=4, attn_chunk=0,
-                              scan_layers=scan)
+                              scan_layers=True)
             mesh = make_mesh(mesh_shape, ("data", "model"))
             trainer = Trainer(cfg, mesh, rules)
             data = SyntheticLM(model_cfg.vocab_size, seed=0)
@@ -174,9 +152,7 @@ def test_elastic_reshard_resume():
     """Train on (2,2), checkpoint, restore onto (4,2) and (1,2) — loss
     trajectory must continue identically. Elastic events change the DATA
     degree only (TP is an architecture property; see runtime/elastic.py),
-    so the pool-space optimizer state shapes are preserved. Un-xfailed by
-    the overlap-engine restructure (scan_layers switches off on jax<0.5
-    only — see module docstring)."""
+    so the pool-space optimizer state shapes are preserved."""
     out = run_multi_device("""
         import tempfile
         from repro.checkpoint.manager import CheckpointManager
@@ -188,10 +164,6 @@ def test_elastic_reshard_resume():
         from repro.launch.trainer import Trainer
 
         model_cfg, rules = get_smoke("olmo-1b")
-        # scan_layers only where the partitioner survives it (see the
-        # module docstring / test_trainer_2x2's version switch).
-        scan = tuple(int(x) for x in
-                     jax.__version__.split(".")[:2]) >= (0, 5)
         def make(mesh_shape, gb=4):
             gf = GradientFlowConfig(mode="lazy", bucket_elems=4096,
                                     wire_dtype="float32", warmup_steps=0)
@@ -201,7 +173,7 @@ def test_elastic_reshard_resume():
                                   warmup_steps=1, total_steps=20,
                                   schedule="constant"),
                               seq_len=32, global_batch=gb, attn_chunk=0,
-                              scan_layers=scan)
+                              scan_layers=True)
             mesh = make_mesh(mesh_shape, ("data", "model"))
             return Trainer(cfg, mesh, rules), mesh
 

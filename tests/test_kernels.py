@@ -77,12 +77,13 @@ def test_fused_update_mask_semantics():
                                1.0 - expected_u, rtol=1e-6)
 
 
-@pytest.mark.parametrize("tile", [0, 777])
+@pytest.mark.parametrize("tile", [0, 3072])
 def test_update_unpack_variant_streams_and_matches_fused_update(tile):
     """fused_update's tiled ``update_unpack`` variant: same Algorithm-1
     math as ``fused_update`` (shared ``update_math``), leaves DMA'd out
     per tile instead of a new master pool — including with a ragged
-    forced tile."""
+    forced tile (3072: the smallest granule multiple that leaves 4096
+    ragged)."""
     from repro.kernels.fused_update import update_unpack as k_uu
     offsets, sizes = (0, 1000, 3500), (1000, 2500, 300)
     n = 4096  # 296 elements of padding

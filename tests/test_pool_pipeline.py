@@ -15,12 +15,16 @@ from repro.optim import sgd
 
 CHUNK = 256
 SIZES = [(7,), (33, 5), (2, 3, 4), (129,), (64, 2)]
+# Forced-tile tests: a kernel tile is a whole number of 1-D granules
+# (tiling.GRANULE = 1024 elements), so these trees span several granules
+# and keep unaligned segment boundaries (6,627 elements; 6,656 padded).
+BIG_SIZES = [(7,), (33, 50), (2, 3, 400), (1290,), (64, 20)]
 
 
-def make_tree(dtype=jnp.float32, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), len(SIZES))
+def make_tree(dtype=jnp.float32, seed=0, sizes=SIZES):
+    ks = jax.random.split(jax.random.PRNGKey(seed), len(sizes))
     return {f"t{i}": jax.random.normal(k, s, jnp.float32).astype(dtype)
-            for i, (k, s) in enumerate(zip(ks, SIZES))}
+            for i, (k, s) in enumerate(zip(ks, sizes))}
 
 
 def concat_oracle(pool: GradientPool, tree, dtype):
@@ -305,11 +309,12 @@ def test_tile_schedule_covers_pool_exactly_once():
 
 
 @pytest.mark.parametrize("wire", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("tile_chunks", [1, 2])
+@pytest.mark.parametrize("tile_chunks", [4, 8])
 def test_pack_kernel_straddling_tile_boundaries(wire, tile_chunks):
-    """Tiny forced tiles: every segment larger than a tile straddles at
-    least one boundary; output must still match the ref twin exactly."""
-    tree = make_tree()
+    """Small forced tiles (one and two granules): every segment larger
+    than a tile straddles at least one boundary; output must still match
+    the ref twin exactly."""
+    tree = make_tree(sizes=BIG_SIZES)
     pool = GradientPool(tree, pad_to=CHUNK)
     leaves = pool.flat_leaves(tree)
     got_p, got_n = k_pack(tuple(leaves), pool.offsets, pool.sizes,
@@ -322,12 +327,12 @@ def test_pack_kernel_straddling_tile_boundaries(wire, tile_chunks):
                                rtol=2e-5)
 
 
-@pytest.mark.parametrize("tile", [64, 100, 177])
+@pytest.mark.parametrize("tile", [1024, 2048, 3072])
 def test_pack_kernel_ragged_final_tile(tile):
     """Pools whose (padded) size is NOT a multiple of the tile: the final
     grid step is a ragged edge block (no census: pad_to=1 keeps the pool
     size odd too)."""
-    tree = make_tree()
+    tree = make_tree(sizes=BIG_SIZES)
     pool = GradientPool(tree, pad_to=1)
     assert pool.size % tile != 0
     leaves = pool.flat_leaves(tree)
@@ -340,9 +345,9 @@ def test_pack_kernel_ragged_final_tile(tile):
     np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
 
 
-@pytest.mark.parametrize("tile", [100, CHUNK])
+@pytest.mark.parametrize("tile", [1024, 2048])
 def test_unpack_kernel_straddling_and_ragged_tiles(tile):
-    pool = GradientPool(make_tree(), pad_to=CHUNK)
+    pool = GradientPool(make_tree(sizes=BIG_SIZES), pad_to=CHUNK)
     n = pool.size
     ks = jax.random.split(jax.random.PRNGKey(21), 4)
     master = jax.random.normal(ks[0], (n,))
@@ -366,7 +371,7 @@ def test_unpack_kernel_straddling_and_ragged_tiles(tile):
 def test_unpack_kernel_lars_ratios_match_expanded_scale():
     """The in-kernel per-tile ratio expansion must equal the ref path fed
     the pool-sized expanded scale (the buffer the kernel never builds)."""
-    pool = GradientPool(make_tree(), pad_to=CHUNK)
+    pool = GradientPool(make_tree(sizes=BIG_SIZES), pad_to=CHUNK)
     n = pool.size
     ks = jax.random.split(jax.random.PRNGKey(23), 4)
     master = jax.random.normal(ks[0], (n,))
@@ -379,7 +384,7 @@ def test_unpack_kernel_lars_ratios_match_expanded_scale():
     got_l, got_m = k_unpack(master, grads, mom, mask, pool.offsets,
                             pool.sizes, lr=0.05, momentum=0.9,
                             weight_decay=1e-4, ratios=ratios,
-                            tile_elems=100, interpret=True)
+                            tile_elems=1024, interpret=True)
     want_l, want_m = ref.pool_unpack_update(
         master, grads, mom, mask, pool.offsets, pool.sizes, lr=0.05,
         momentum=0.9, weight_decay=1e-4, scale=expanded)

@@ -70,9 +70,9 @@ _EQUIV_BODY = """
     for size in (N * 37, N * 5 + 3, max(N - 3, 1)):
         for wire in ("float32", "bfloat16"):
             wire = jnp.dtype(wire)
-            # check_vma=False pins the full 2(N-1) ring on every jax
-            # version (a checked region on new jax would reject the
-            # varying-tagged ppermute chain and reroute to the vma twin)
+            # check_vma=False pins the full 2(N-1) ring (a checked
+            # region would reject the varying-tagged ppermute chain and
+            # reroute to the vma twin)
             def f(x):
                 xw = x.astype(wire)
                 ring = ref.ring_allreduce(xw, "data")        # standalone

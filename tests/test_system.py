@@ -64,12 +64,8 @@ def test_csc_converges_close_to_dense(dense_losses):
     """Paper Table 3: sparse communication trains to (near) parity."""
     csc = run_training("csc", sparsity=0.75)
     assert np.isfinite(csc).all()
-    # end-of-run loss within a modest margin of dense. The margin was
-    # calibrated on current jax; the 0.4.x compat path (legacy shard_map +
-    # older XLA CPU bf16 reductions) lands ~0.18 on the same seed, so it
-    # gets a correspondingly looser bound.
-    margin = 0.15 if hasattr(jax, "shard_map") else 0.25
-    assert csc[-5:].mean() < dense_losses[-5:].mean() + margin
+    # end-of-run loss within a modest margin of dense.
+    assert csc[-5:].mean() < dense_losses[-5:].mean() + 0.15
 
 
 def test_momentum_correction_matters():
