@@ -1,0 +1,8 @@
+"""The HBM roofline share of the ``csc_compact`` kernel, in percent: the
+bytes it needs per step (``bench.counts.pool_kernel_bytes``) at the
+chip's published HBM bandwidth, over its device time per step."""
+from bench import tracing
+
+
+def read(ctx):
+    return tracing.roofline_pct(ctx, ["csc_compact"])
