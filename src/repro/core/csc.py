@@ -66,19 +66,26 @@ def init_state(pool_size: int, chunk_elems: int,
 
 
 def select_chunks(chunk_norms: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
-    """Top-k chunk ids (sorted ascending for deterministic layout) + mask."""
+    """Top-k chunk ids (sorted ascending for deterministic layout) + mask.
+    Selection, compaction and the census run in scope ``gf.csc_select``."""
     num_chunks = chunk_norms.shape[0]
-    _, idx = jax.lax.top_k(chunk_norms, k)
-    idx = jnp.sort(idx)
-    mask = jnp.zeros((num_chunks,), dtype=jnp.bool_).at[idx].set(True)
+    with jax.named_scope("gf.csc_select"):
+        _, idx = jax.lax.top_k(chunk_norms, k)
+        idx = jnp.sort(idx)
+        mask = jnp.zeros((num_chunks,), dtype=jnp.bool_).at[idx].set(True)
     return idx, mask
 
 
-def compact_chunks(pool: jax.Array, idx: jax.Array,
-                   chunk_elems: int) -> jax.Array:
-    """Gather selected chunks into the dense wire buffer (k*chunk,)."""
-    chunks = pool.reshape((-1, chunk_elems))
-    return jnp.take(chunks, idx, axis=0).reshape((-1,))
+def compact_chunks(pool: jax.Array, idx: jax.Array, chunk_elems: int, *,
+                   use_kernels: bool = False) -> jax.Array:
+    """Gather selected chunks into the dense wire buffer (k*chunk,);
+    through the ``csc_compact`` kernel with ``use_kernels``."""
+    with jax.named_scope("gf.csc_select"):
+        if use_kernels:
+            from repro.kernels import ops as kops
+            return kops.csc_compact(pool, idx, chunk_elems)
+        chunks = pool.reshape((-1, chunk_elems))
+        return jnp.take(chunks, idx, axis=0).reshape((-1,))
 
 
 def scatter_chunks(pool: jax.Array, idx: jax.Array, values: jax.Array,
@@ -89,12 +96,17 @@ def scatter_chunks(pool: jax.Array, idx: jax.Array, values: jax.Array,
     return chunks.reshape((-1,))
 
 
-def chunk_l1_norms(pool: jax.Array, chunk_elems: int) -> jax.Array:
+def chunk_l1_norms(pool: jax.Array, chunk_elems: int, *,
+                   use_kernels: bool = False) -> jax.Array:
     """Per-chunk L1 norm; f32 accumulate regardless of pool dtype.
     Delegates to the kernel oracle so the census has one definition —
-    the same math the fused pack emits in its single pass."""
-    from repro.kernels import ref
-    return ref.chunk_l1norm(pool, chunk_elems)
+    the same math the fused pack emits in its single pass — or, with
+    ``use_kernels``, to the ``chunk_l1norm`` kernel."""
+    from repro.kernels import ops as kops, ref
+    with jax.named_scope("gf.csc_select"):
+        if use_kernels:
+            return kops.chunk_l1norm(pool, chunk_elems)
+        return ref.chunk_l1norm(pool, chunk_elems)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,11 +179,7 @@ def csc_reduce(
     g_send = g if (spec is None or residual is None) else g + residual
 
     # Pack important chunks; fused bucketed allreduce over the wire buffer.
-    if cfg.use_kernels:
-        from repro.kernels import ops as kops
-        wire = kops.csc_compact(g_send, idx, chunk)
-    else:
-        wire = compact_chunks(g_send, idx, chunk)
+    wire = compact_chunks(g_send, idx, chunk, use_kernels=cfg.use_kernels)
     residual_new = residual
     if spec is None:
         reduced = bucketed_reduce(
@@ -214,11 +222,7 @@ def csc_reduce(
     # Fig 18: next-iteration importance. Post-reduce pool: important chunks
     # hold the mean (≡ paper's sum/N), others hold local g — L1 per chunk,
     # then a (cheap) psum so every device agrees.
-    if cfg.use_kernels:
-        from repro.kernels import ops as kops
-        l1 = kops.chunk_l1norm(g_out, chunk)
-    else:
-        l1 = chunk_l1_norms(g_out, chunk)
+    l1 = chunk_l1_norms(g_out, chunk, use_kernels=cfg.use_kernels)
     if spec is not None:
         # Quantized wires: selected chunks contribute their PRE-QUANT
         # send-buffer L1 instead of the post-dequant mean's. Three birds,

@@ -323,6 +323,14 @@ class OverlapEngine:
 
     # -- public entry point --------------------------------------------------
 
+    def master_pool(self, params_tree):
+        """The f32 master pool the update reads, packed anew from the
+        params every step (scope ``gf.master_pack``)."""
+        with jax.named_scope("gf.master_pack"):
+            master, _ = self.pool.pack(params_tree, dtype=jnp.float32,
+                                       use_kernels=self.gf.cfg.use_kernels)
+        return master
+
     def run(self, plan: StepPlan, gpool, params_tree, opt_state,
             gfstate, lr, census=None):
         """One pipelined reduce+update phase. ``gpool`` is the local
@@ -334,10 +342,8 @@ class OverlapEngine:
         recomputed here when None — one extra pool pass). Returns
         (new_params_tree, new_opt_state, new_gfstate)."""
         cfg = self.gf.cfg
-        use_k = cfg.use_kernels
         prepacked = cfg.mode in ("dense", "lazy")
-        master, _ = self.pool.pack(params_tree, dtype=jnp.float32,
-                                   use_kernels=use_k)
+        master = self.master_pool(params_tree)
         if cfg.mode == "csc" and not plan.warmup:
             return self._run_csc(plan, gpool, master, opt_state, gfstate,
                                  lr)
@@ -381,8 +387,7 @@ class OverlapEngine:
         gcfg = cfg.guard
         assert gcfg is not None, "run_guarded needs GradientFlowConfig.guard"
         limit = guard_mod.overflow_limit(gcfg, cfg.wire_dtype)
-        master, _ = self.pool.pack(params_tree, dtype=jnp.float32,
-                                   use_kernels=cfg.use_kernels)
+        master = self.master_pool(params_tree)
         if cfg.mode == "csc" and not plan.warmup:
             out = self._guarded_csc(plan, gpool, master, params_tree,
                                     opt_state, gfstate, scaler_state, lr,
@@ -439,8 +444,10 @@ class OverlapEngine:
         new_params, opt2 = jax.lax.optimization_barrier(
             self._assemble(outs))
         pick = lambda new, old: jnp.where(ok, new, old)
-        new_params = jax.tree_util.tree_map(pick, new_params, params_tree)
-        opt2 = jax.tree_util.tree_map(pick, opt2, opt_state)
+        with jax.named_scope("gf.update"):
+            new_params = jax.tree_util.tree_map(pick, new_params,
+                                                params_tree)
+            opt2 = jax.tree_util.tree_map(pick, opt2, opt_state)
         return new_params, opt2, gfstate, flags
 
     # -- quantized wire formats (int8 / fp8) ----------------------------------
@@ -568,11 +575,8 @@ class OverlapEngine:
         # directly to the send values of the selected chunks.
         g_send = g + gfstate.residual if (spec is not None and feedback) \
             else g
-        if cfg.use_kernels:
-            from repro.kernels import ops as kops
-            wire = kops.csc_compact(g_send, idx, chunk)
-        else:
-            wire = csc_mod.compact_chunks(g_send, idx, chunk)
+        wire = csc_mod.compact_chunks(g_send, idx, chunk,
+                                      use_kernels=cfg.use_kernels)
         scales = None
         send_l1 = None
         residual_new = gfstate.residual
@@ -613,11 +617,8 @@ class OverlapEngine:
 
         hg_new = jnp.where(elem_mask, 0.0,
                            cfg.momentum * g_out).astype(gfstate.hg.dtype)
-        if cfg.use_kernels:
-            from repro.kernels import ops as kops
-            l1 = kops.chunk_l1norm(g_out, chunk)
-        else:
-            l1 = csc_mod.chunk_l1_norms(g_out, chunk)
+        l1 = csc_mod.chunk_l1_norms(g_out, chunk,
+                                    use_kernels=cfg.use_kernels)
         if send_l1 is not None:
             l1 = l1.at[idx].set(send_l1)
         norms_new = reduce_pool(l1, plan.reduce_axes)
@@ -728,11 +729,8 @@ class OverlapEngine:
         elem_mask = jnp.repeat(chunk_mask, chunk)
         g_send = g + gfstate.residual if (spec is not None and feedback) \
             else g
-        if cfg.use_kernels:
-            from repro.kernels import ops as kops
-            wire = kops.csc_compact(g_send, idx, chunk)
-        else:
-            wire = csc_mod.compact_chunks(g_send, idx, chunk)
+        wire = csc_mod.compact_chunks(g_send, idx, chunk,
+                                      use_kernels=cfg.use_kernels)
         scales = None
         residual_new = gfstate.residual
         wire_dt = cfg.wire_dtype
@@ -773,11 +771,8 @@ class OverlapEngine:
         # issued BEFORE the update spans so they overlap the update sweep.
         hg_new = jnp.where(elem_mask, 0.0,
                            cfg.momentum * g_out).astype(gfstate.hg.dtype)
-        if cfg.use_kernels:
-            from repro.kernels import ops as kops
-            l1 = kops.chunk_l1norm(g_out, chunk)
-        else:
-            l1 = csc_mod.chunk_l1_norms(g_out, chunk)
+        l1 = csc_mod.chunk_l1_norms(g_out, chunk,
+                                    use_kernels=cfg.use_kernels)
         if send_l1 is not None:
             l1 = l1.at[idx].set(send_l1)
         norms_new = reduce_pool(l1, plan.reduce_axes)
@@ -837,13 +832,14 @@ class OverlapEngine:
         to the bucket span). Returns (leaves, new_state_seg)."""
         start, end = span
         view = self.pool.bucket_view(start, end)
-        m_seg = _seg(master, start, end)
-        st_seg = jax.tree_util.tree_map(lambda a: _seg(a, start, end),
-                                        opt_state)
-        mask_seg = jnp.ones((view.size,), jnp.bool_) if mask is None \
-            else _seg(mask, start, end)
-        return self._update_view_seg(view, m_seg, red_seg, st_seg, lr,
-                                     mask_seg)
+        with jax.named_scope("gf.update"):
+            m_seg = _seg(master, start, end)
+            st_seg = jax.tree_util.tree_map(lambda a: _seg(a, start, end),
+                                            opt_state)
+            mask_seg = jnp.ones((view.size,), jnp.bool_) if mask is None \
+                else _seg(mask, start, end)
+            return self._update_view_seg(view, m_seg, red_seg, st_seg, lr,
+                                         mask_seg)
 
     def _update_view_seg(self, view, m_seg, red_seg, st_seg, lr, mask_seg):
         """The span update on pre-sliced segments — shared by the in-step
@@ -886,13 +882,14 @@ class OverlapEngine:
         all_leaves = [leaf for leaves, _ in outs for leaf in leaves]
         assert len(all_leaves) == self.pool.num_tensors, (
             len(all_leaves), self.pool.num_tensors)
-        new_params = self.pool.unflatten(all_leaves)
-        states = [st for _, st in outs]
-        if len(states) == 1:
-            opt2 = states[0]
-        else:
-            opt2 = jax.tree_util.tree_map(
-                lambda *segs: jnp.concatenate(segs), *states)
+        with jax.named_scope("gf.update"):
+            new_params = self.pool.unflatten(all_leaves)
+            states = [st for _, st in outs]
+            if len(states) == 1:
+                opt2 = states[0]
+            else:
+                opt2 = jax.tree_util.tree_map(
+                    lambda *segs: jnp.concatenate(segs), *states)
         return new_params, opt2
 
     # -- cross-step pipelining (the deferred-tail lane) -----------------------
@@ -964,11 +961,12 @@ class OverlapEngine:
         emitter's segments can never perturb params."""
         if not plan.pipeline_tail:
             return params_tree, opt_state
-        new_params, opt2 = jax.lax.optimization_barrier(
-            self._apply_lane_tree(plan, params_tree, opt_state, lane))
-        pick = lambda new, old: jnp.where(lane.ok, new, old)
-        return (jax.tree_util.tree_map(pick, new_params, params_tree),
-                jax.tree_util.tree_map(pick, opt2, opt_state))
+        with jax.named_scope("gf.apply_inflight"):
+            new_params, opt2 = jax.lax.optimization_barrier(
+                self._apply_lane_tree(plan, params_tree, opt_state, lane))
+            pick = lambda new, old: jnp.where(lane.ok, new, old)
+            return (jax.tree_util.tree_map(pick, new_params, params_tree),
+                    jax.tree_util.tree_map(pick, opt2, opt_state))
 
     def _identity_span(self, span, master, opt_state):
         """The no-op twin of ``_update_span``: the span's current master
@@ -978,11 +976,12 @@ class OverlapEngine:
         step's assembly."""
         start, end = span
         view = self.pool.bucket_view(start, end)
-        leaves = [_seg(master, start + o, start + o + s).astype(spec.dtype)
-                  for spec, o, s in zip(view.specs, view.offsets,
-                                        view.sizes)]
-        st_seg = jax.tree_util.tree_map(lambda a: _seg(a, start, end),
-                                        opt_state)
+        with jax.named_scope("gf.update"):
+            leaves = [_seg(master, start + o, start + o + s).astype(
+                spec.dtype) for spec, o, s in zip(view.specs, view.offsets,
+                                                  view.sizes)]
+            st_seg = jax.tree_util.tree_map(lambda a: _seg(a, start, end),
+                                            opt_state)
         return leaves, st_seg
 
     def _pipelined_pool_stage(self, plan, gpool, master, opt_state, lr):
@@ -1030,8 +1029,7 @@ class OverlapEngine:
         cfg = self.gf.cfg
         assert plan.pipeline_tail and cfg.mode in ("dense", "lazy") \
             and self.gf.wire_spec is None, plan
-        master, _ = self.pool.pack(params_tree, dtype=jnp.float32,
-                                   use_kernels=cfg.use_kernels)
+        master = self.master_pool(params_tree)
         outs, lane = self._pipelined_pool_stage(plan, gpool, master,
                                                 opt_state, lr)
         new_params, opt2 = self._assemble(outs)
@@ -1063,8 +1061,7 @@ class OverlapEngine:
         assert plan.pipeline_tail and cfg.mode in ("dense", "lazy") \
             and self.gf.wire_spec is None, plan
         limit = guard_mod.overflow_limit(gcfg, cfg.wire_dtype)
-        master, _ = self.pool.pack(params_tree, dtype=jnp.float32,
-                                   use_kernels=cfg.use_kernels)
+        master = self.master_pool(params_tree)
         segs = []
         for task in plan.tasks:
             segs.append(lazy_mod.reduce_bucket(
@@ -1082,8 +1079,10 @@ class OverlapEngine:
         new_params, opt2 = jax.lax.optimization_barrier(
             self._assemble(outs))
         pick = lambda new, old: jnp.where(ok, new, old)
-        new_params = jax.tree_util.tree_map(pick, new_params, params_tree)
-        opt2 = jax.tree_util.tree_map(pick, opt2, opt_state)
+        with jax.named_scope("gf.update"):
+            new_params = jax.tree_util.tree_map(pick, new_params,
+                                                params_tree)
+            opt2 = jax.tree_util.tree_map(pick, opt2, opt_state)
         lane = InflightLane(
             segs=tuple(segs[t.index] / scale for t in plan.tail_tasks),
             lr=jnp.asarray(lr, jnp.float32), ok=ok)
@@ -1163,9 +1162,10 @@ class OverlapEngine:
                 algo=task.algo) / nds
             if pending is not None:
                 pt, pr = pending
-                new_m[pt.index], new_st[pt.index] = self._seg_update(
-                    pt, m_segs[pt.index], st_segs[pt.index], pr, lr,
-                    None)
+                with jax.named_scope("gf.update"):
+                    new_m[pt.index], new_st[pt.index] = self._seg_update(
+                        pt, m_segs[pt.index], st_segs[pt.index], pr, lr,
+                        None)
                 pending = None
             if task.commit_epoch:
                 tail_segs.append(red)
@@ -1173,12 +1173,14 @@ class OverlapEngine:
                 pending = (task, red)
         if pending is not None:
             pt, pr = pending
-            new_m[pt.index], new_st[pt.index] = self._seg_update(
-                pt, m_segs[pt.index], st_segs[pt.index], pr, lr, None)
-        for task, red in zip(plan.tail_tasks, lane.segs):
-            new_m[task.index], new_st[task.index] = self._seg_update(
-                task, m_segs[task.index], st_segs[task.index], red,
-                lane.lr, lane.ok)
+            with jax.named_scope("gf.update"):
+                new_m[pt.index], new_st[pt.index] = self._seg_update(
+                    pt, m_segs[pt.index], st_segs[pt.index], pr, lr, None)
+        with jax.named_scope("gf.apply_inflight"):
+            for task, red in zip(plan.tail_tasks, lane.segs):
+                new_m[task.index], new_st[task.index] = self._seg_update(
+                    task, m_segs[task.index], st_segs[task.index], red,
+                    lane.lr, lane.ok)
         lane2 = InflightLane(segs=tuple(tail_segs),
                              lr=jnp.asarray(lr, jnp.float32),
                              ok=jnp.ones((), jnp.bool_))
@@ -1192,10 +1194,11 @@ class OverlapEngine:
         if not plan.pipeline_tail:
             return m_segs, st_segs
         new_m, new_st = list(m_segs), list(st_segs)
-        for task, red in zip(plan.tail_tasks, lane.segs):
-            new_m[task.index], new_st[task.index] = self._seg_update(
-                task, m_segs[task.index], st_segs[task.index], red,
-                lane.lr, lane.ok)
+        with jax.named_scope("gf.apply_inflight"):
+            for task, red in zip(plan.tail_tasks, lane.segs):
+                new_m[task.index], new_st[task.index] = self._seg_update(
+                    task, m_segs[task.index], st_segs[task.index], red,
+                    lane.lr, lane.ok)
         return tuple(new_m), tuple(new_st)
 
 

@@ -260,49 +260,52 @@ class GradientFlow:
         quantization error is divided by it on write and re-multiplied on
         read — scaler backoffs never corrupt carried feedback.
         """
-        cfg = self.cfg
-        if cfg.mode == "csc":
-            assert not prepacked, (
-                "CSC consumes the f32 pool: pack with dtype=float32")
-            stage = stage or self.stages[-1]
-            k = stage.num_selected
-            if k >= self.num_chunks:
-                # Warm-up dense stage: full pool via the lazy path, but the
-                # CSC state must keep tracking norms for the handoff.
-                # Quantized runs keep NATIVE transport here: the very
-                # first iterations have no trustworthy census basis yet,
-                # and warm-up is by definition the dense phase.
-                return self._dense_or_lazy_with_norms(pool_grads, state)
-            wire_bounds = csc_mod.wire_bucket_boundaries(
-                k, cfg.chunk_elems, self.bucket_elems)
-            feedback = cfg.feedback_enabled
-            res = csc_mod.csc_reduce(
-                pool_grads,
-                csc_mod.CSCState(hg=state.hg, chunk_norms=state.chunk_norms),
-                cfg,
-                num_selected=k,
-                bucket_boundaries=wire_bounds,
-                num_data_shards=self.num_data_shards,
-                algo=self._algos_for(wire_bounds),
-                residual=state.residual if feedback else None,
-            )
-            return res.grads, res.elem_mask, GFState(
-                hg=res.state.hg, chunk_norms=res.state.chunk_norms,
-                residual=res.residual if feedback else state.residual)
+        with jax.named_scope("gf.reduce"):
+            cfg = self.cfg
+            if cfg.mode == "csc":
+                assert not prepacked, (
+                    "CSC consumes the f32 pool: pack with dtype=float32")
+                stage = stage or self.stages[-1]
+                k = stage.num_selected
+                if k >= self.num_chunks:
+                    # Warm-up dense stage: full pool via the lazy path,
+                    # but the CSC state must keep tracking norms for the
+                    # handoff. Quantized runs keep NATIVE transport here:
+                    # the very first iterations have no trustworthy
+                    # census basis yet, and warm-up is by definition the
+                    # dense phase.
+                    return self._dense_or_lazy_with_norms(pool_grads, state)
+                wire_bounds = csc_mod.wire_bucket_boundaries(
+                    k, cfg.chunk_elems, self.bucket_elems)
+                feedback = cfg.feedback_enabled
+                res = csc_mod.csc_reduce(
+                    pool_grads,
+                    csc_mod.CSCState(hg=state.hg,
+                                     chunk_norms=state.chunk_norms),
+                    cfg,
+                    num_selected=k,
+                    bucket_boundaries=wire_bounds,
+                    num_data_shards=self.num_data_shards,
+                    algo=self._algos_for(wire_bounds),
+                    residual=state.residual if feedback else None,
+                )
+                return res.grads, res.elem_mask, GFState(
+                    hg=res.state.hg, chunk_norms=res.state.chunk_norms,
+                    residual=res.residual if feedback else state.residual)
 
-        dense = cfg.mode == "dense"
-        bounds = self._dense_bounds if dense else self._lazy_bounds
-        algos = self._dense_algos if dense else self._lazy_algos
-        if self.wire_spec is not None:
-            return self._quantized_dense_or_lazy(
-                pool_grads, state, bounds, algos, census=census,
-                census_sum=census_sum, loss_scale=loss_scale)
-        wire = None if prepacked else cfg.wire_dtype
-        summed = bucketed_reduce(pool_grads, bounds, cfg.reduce_axes,
-                                 wire, algo=algos)
-        mean = summed / self.num_data_shards
-        mask = jnp.ones(mean.shape, dtype=jnp.bool_)
-        return mean, mask, state
+            dense = cfg.mode == "dense"
+            bounds = self._dense_bounds if dense else self._lazy_bounds
+            algos = self._dense_algos if dense else self._lazy_algos
+            if self.wire_spec is not None:
+                return self._quantized_dense_or_lazy(
+                    pool_grads, state, bounds, algos, census=census,
+                    census_sum=census_sum, loss_scale=loss_scale)
+            wire = None if prepacked else cfg.wire_dtype
+            summed = bucketed_reduce(pool_grads, bounds, cfg.reduce_axes,
+                                     wire, algo=algos)
+            mean = summed / self.num_data_shards
+            mask = jnp.ones(mean.shape, dtype=jnp.bool_)
+            return mean, mask, state
 
     def quantized_scales(self, census_sum: jax.Array) -> jax.Array:
         """Per-chunk wire scales from a rank-invariant census sum."""

@@ -55,21 +55,25 @@ def reduce_bucket(
     in ``accum_dtype``. This is the per-bucket primitive both the
     monolithic ``bucketed_reduce`` and the overlap engine's ``StepPlan``
     execution bottom out in — one definition, so the pipelined and
-    monolithic paths cannot drift."""
-    seg = jax.lax.slice_in_dim(pool, start, end)
-    if wire_dtype is not None:
-        seg = seg.astype(jnp.dtype(wire_dtype))
-    if (jnp.issubdtype(seg.dtype, jnp.floating) and seg.dtype.itemsize == 1
-            and getattr(algo, "name", "flat") != "pallas_ring"):
-        # fp8-e4m3 wire on a psum-based algorithm: XLA would accumulate
-        # in fp8, rounding at every add. Upcast to the accumulator first
-        # — the exact sum of the per-rank fp8 words, i.e. the dequantize-
-        # then-sum reference the ring's per-hop requant is tolerance-
-        # gated against. int8 words sum exactly in any dtype and ride
-        # every algorithm as-is (see repro.core.wire).
-        seg = seg.astype(accum_dtype)
-    seg = reduce_pool(seg, axes, algo=algo)
-    return seg.astype(accum_dtype)
+    monolithic paths cannot drift. Scope ``gf.reduce``, wire casts
+    included."""
+    with jax.named_scope("gf.reduce"):
+        seg = jax.lax.slice_in_dim(pool, start, end)
+        if wire_dtype is not None:
+            seg = seg.astype(jnp.dtype(wire_dtype))
+        if (jnp.issubdtype(seg.dtype, jnp.floating)
+                and seg.dtype.itemsize == 1
+                and getattr(algo, "name", "flat") != "pallas_ring"):
+            # fp8-e4m3 wire on a psum-based algorithm: XLA would
+            # accumulate in fp8, rounding at every add. Upcast to the
+            # accumulator first — the exact sum of the per-rank fp8
+            # words, i.e. the dequantize-then-sum reference the ring's
+            # per-hop requant is tolerance-gated against. int8 words sum
+            # exactly in any dtype and ride every algorithm as-is (see
+            # repro.core.wire).
+            seg = seg.astype(accum_dtype)
+        seg = reduce_pool(seg, axes, algo=algo)
+        return seg.astype(accum_dtype)
 
 
 def bucketed_reduce_parts(

@@ -5,12 +5,18 @@ a file-backed loader with the same (step, shard)-pure interface) and
 prefetches ahead of the training loop on a background thread. Because
 batches are pure functions of (step, shard), skip-ahead after a
 checkpoint-restore is O(1): just start asking for the restored step.
+
+Host spans for ``jax.profiler``: ``repro.data.fetch`` around each batch
+the prefetch thread makes, ``repro.data.get`` around each wait of the
+consumer for one.
 """
 from __future__ import annotations
 
 import queue
 import threading
 from typing import Any, Callable, Dict, Iterator, Optional
+
+import jax
 
 
 class Prefetcher:
@@ -27,7 +33,8 @@ class Prefetcher:
         step = self._next_step
         while not self._stop.is_set():
             try:
-                item = (step, self._fetch(step))
+                with jax.profiler.TraceAnnotation("repro.data.fetch"):
+                    item = (step, self._fetch(step))
             except Exception as e:  # surface loader errors to the consumer
                 self._queue.put((step, e))
                 return
@@ -40,7 +47,8 @@ class Prefetcher:
             step += 1
 
     def get(self, expected_step: int) -> Any:
-        step, item = self._queue.get()
+        with jax.profiler.TraceAnnotation("repro.data.get"):
+            step, item = self._queue.get()
         if isinstance(item, Exception):
             raise item
         assert step == expected_step, (

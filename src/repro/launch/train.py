@@ -40,7 +40,8 @@ class ThroughputMeter:
     starts when that window finishes. Fixes the two historical log lies
     — a resumed run crediting itself with the pre-resume steps
     (``(step + 1) * batch * seq`` from a clock started this process),
-    and the compile time of step 0 folded into every later rate."""
+    and the compile time of step 0 folded into every later rate. The
+    clock is ``time.perf_counter``, which never steps."""
 
     def __init__(self, tokens_per_step: float):
         self.tokens_per_step = tokens_per_step
@@ -49,7 +50,7 @@ class ThroughputMeter:
 
     def note(self, n_steps: int, now: Optional[float] = None) -> None:
         """Record ``n_steps`` just finished."""
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         if self._t0 is None:
             self._t0 = now  # first (compile) window only starts the clock
         else:
@@ -59,7 +60,7 @@ class ThroughputMeter:
         """tok/s, or None until any post-compile step has finished."""
         if self._t0 is None or self._steps == 0:
             return None
-        now = time.time() if now is None else now
+        now = time.perf_counter() if now is None else now
         return self._steps * self.tokens_per_step / (now - self._t0)
 
 
@@ -213,19 +214,26 @@ class WindowLoop:
 
     def batches(self, step: int, length: int):
         """The window's batches, stacked and placed with the data
-        sharding."""
-        batches = [self.pipe.next_at(step + i) for i in range(length)]
-        return jax.device_put(
-            jax.tree_util.tree_map(lambda *xs: np.stack(xs), *batches),
-            window_sharding(self.trainer, batches[0]))
+        sharding. Host spans: ``repro.loop.batches`` around it all, with
+        the pipeline's ``repro.data.get`` waits and ``repro.loop.place``
+        (stack and ``device_put``) inside."""
+        with jax.profiler.TraceAnnotation("repro.loop.batches"):
+            batches = [self.pipe.next_at(step + i) for i in range(length)]
+            with jax.profiler.TraceAnnotation("repro.loop.place"):
+                return jax.device_put(
+                    jax.tree_util.tree_map(lambda *xs: np.stack(xs),
+                                           *batches),
+                    window_sharding(self.trainer, batches[0]))
 
     def __call__(self, step: int, length: int, state):
         stage = self.stage_at(step)
         stacked = self.batches(step, length)
         exe = self.executable(stage, state, stacked)
         t0 = time.perf_counter()
-        state, metrics = exe(state, stacked)
-        win_losses = np.asarray(metrics["loss"], np.float32)
+        with jax.profiler.TraceAnnotation("repro.loop.dispatch"):
+            state, metrics = exe(state, stacked)
+        with jax.profiler.TraceAnnotation("repro.loop.loss_read"):
+            win_losses = np.asarray(metrics["loss"], np.float32)
         self.window_seconds.append(time.perf_counter() - t0)
         self.losses.extend(float(x) for x in win_losses)
         self.meter.note(length)

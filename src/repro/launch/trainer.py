@@ -36,6 +36,13 @@ same manual region — no trainer-side plumbing beyond the config string
 ``GradientFlowConfig.overlap`` selects staged (per-bucket pipeline,
 default) vs monolithic (the old barrier chain) execution of the update
 region; both are numerically equivalent (tests/test_engine.py).
+
+Device scopes (``jax.named_scope``; they reach each compiled
+instruction's ``op_name`` and so name device time in a profile):
+``model`` holds forward, loss and head, its backward showing as
+``transpose(jvp(model))``; GradientFlow's phases are ``gf.pack``,
+``gf.master_pack``, ``gf.reduce``, ``gf.update``, ``gf.apply_inflight``
+and ``gf.csc_select`` (docs/architecture.md, "Reading a trace").
 """
 from __future__ import annotations
 
@@ -396,8 +403,7 @@ class Trainer:
         reduced, mask, gf2 = self.gf.reduce(gpool, gf_local, stage=stage,
                                             prepacked=prepacked,
                                             census=census)
-        master, _ = self.pool.pack(params, dtype=jnp.float32,
-                                   use_kernels=cfg.use_kernels)
+        master = self.engine.master_pool(params)
         scale = ratios = None
         if self.lars is not None:
             r = self.lars.ratios(master, reduced, self.cfg.optimizer, mask)
@@ -483,8 +489,7 @@ class Trainer:
             flags = guard_mod.flags_from_words(
                 [guard_mod.health_word(reduced)], limit)
             red = reduced / scaler.scale
-        master, _ = self.pool.pack(params, dtype=jnp.float32,
-                                   use_kernels=cfg.use_kernels)
+        master = self.engine.master_pool(params)
 
         def commit():
             scale = ratios = None
@@ -661,16 +666,18 @@ class Trainer:
             ``norms_chunk`` fuses the chunk-L1 census into the same pass
             when the wire scales need it (quantized dense/lazy). Returns
             (gpool[, staging][, census]) per the static flags."""
-            if st:
-                gpool, census, staging = self.pool.pack_into(
-                    st[0], grads, dtype=self._pack_dtype,
-                    norms_chunk=norms_chunk,
-                    use_kernels=self.gf_cfg.use_kernels)
-            else:
-                gpool, census = self.pool.pack(
-                    grads, dtype=self._pack_dtype, norms_chunk=norms_chunk,
-                    use_kernels=self.gf_cfg.use_kernels)
-                staging = gpool
+            with jax.named_scope("gf.pack"):
+                if st:
+                    gpool, census, staging = self.pool.pack_into(
+                        st[0], grads, dtype=self._pack_dtype,
+                        norms_chunk=norms_chunk,
+                        use_kernels=self.gf_cfg.use_kernels)
+                else:
+                    gpool, census = self.pool.pack(
+                        grads, dtype=self._pack_dtype,
+                        norms_chunk=norms_chunk,
+                        use_kernels=self.gf_cfg.use_kernels)
+                    staging = gpool
             outs = (gpool,)
             if staging_on:
                 outs += (staging,)
@@ -693,15 +700,17 @@ class Trainer:
                 lambda x: _pvary(x, self.data_axes), params)
 
             def loss_fn(p):
-                cp = jax.tree_util.tree_map(
-                    lambda x: x.astype(compute_dtype), p)
-                loss, metrics = self.model.loss_fn(
-                    cp, batch, rules=rules, remat=cfg.remat,
-                    scan_layers=cfg.scan_layers, attn_chunk=cfg.attn_chunk,
-                    causal_skip=cfg.causal_skip,
-                    compute_dtype=compute_dtype)
-                if loss_scale is not None:
-                    loss = loss * loss_scale
+                with jax.named_scope("model"):
+                    cp = jax.tree_util.tree_map(
+                        lambda x: x.astype(compute_dtype), p)
+                    loss, metrics = self.model.loss_fn(
+                        cp, batch, rules=rules, remat=cfg.remat,
+                        scan_layers=cfg.scan_layers,
+                        attn_chunk=cfg.attn_chunk,
+                        causal_skip=cfg.causal_skip,
+                        compute_dtype=compute_dtype)
+                    if loss_scale is not None:
+                        loss = loss * loss_scale
                 return loss, metrics
 
             if cfg.microbatches > 1:
@@ -711,8 +720,11 @@ class Trainer:
                 (_, metrics), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params_v)
             if self.data_axes:
-                metrics = jax.tree_util.tree_map(
-                    lambda m: jax.lax.pmean(m, self.data_axes), metrics)
+                # The loss's mean over the data shards: a collective of
+                # the step, so under the scope every collective is in.
+                with jax.named_scope("gf.reduce"):
+                    metrics = jax.tree_util.tree_map(
+                        lambda m: jax.lax.pmean(m, self.data_axes), metrics)
             # Hand off grads to the sibling update region in POOL form:
             # the pack runs here (model-local space), so no scanned-layer
             # gradient ever crosses the region boundary — only a flat 1-D
@@ -968,17 +980,18 @@ class Trainer:
             return (gacc, macc), None
 
         def loss_fn_mb(p, mb):
-            cp = jax.tree_util.tree_map(
-                lambda x: x.astype(jnp.dtype(self.cfg.model.compute_dtype)),
-                p)
-            loss, metrics = self.model.loss_fn(
-                cp, mb, rules=self.rules, remat=self.cfg.remat,
-                scan_layers=self.cfg.scan_layers,
-                attn_chunk=self.cfg.attn_chunk,
-                causal_skip=self.cfg.causal_skip,
-                compute_dtype=jnp.dtype(self.cfg.model.compute_dtype))
-            if loss_scale is not None:
-                loss = loss * loss_scale
+            with jax.named_scope("model"):
+                cp = jax.tree_util.tree_map(
+                    lambda x: x.astype(
+                        jnp.dtype(self.cfg.model.compute_dtype)), p)
+                loss, metrics = self.model.loss_fn(
+                    cp, mb, rules=self.rules, remat=self.cfg.remat,
+                    scan_layers=self.cfg.scan_layers,
+                    attn_chunk=self.cfg.attn_chunk,
+                    causal_skip=self.cfg.causal_skip,
+                    compute_dtype=jnp.dtype(self.cfg.model.compute_dtype))
+                if loss_scale is not None:
+                    loss = loss * loss_scale
             return loss, metrics
 
         (grads, metrics), _ = jax.lax.scan(body, (g0, m0), split)

@@ -1,3 +1,5 @@
+import jax
+
 from repro.optim import adamw, lars, schedules, sgd
 
 
@@ -34,20 +36,21 @@ def update_unpack(name: str, pool, master, grads, state, mask, cfg, lr, *,
     as the per-tensor ``ratios`` vector — expanded per tile in-kernel, no
     pool-sized scale buffer); optimizers without a fused kernel (adamw)
     fall back to update_pool + the static-slice unravel — same output
-    pytree, one extra pool pass."""
-    if name in ("momentum_sgd", "lars"):
-        return sgd.update_unpack(pool, master, grads, state, mask, cfg, lr,
-                                 scale=scale, ratios=ratios,
-                                 use_kernels=use_kernels,
-                                 tile_elems=tile_elems)
-    if ratios is not None:
-        from repro.kernels import ref
-        assert scale is None
-        scale = ref.expand_ratios(ratios, pool.sizes, pool.size)
-    new_master, new_state = update_pool(name, master, grads, state, mask,
-                                        cfg, lr, scale=scale,
-                                        use_kernels=use_kernels)
-    return pool.unravel(new_master), new_state
+    pytree, one extra pool pass. Scope ``gf.update``."""
+    with jax.named_scope("gf.update"):
+        if name in ("momentum_sgd", "lars"):
+            return sgd.update_unpack(pool, master, grads, state, mask, cfg,
+                                     lr, scale=scale, ratios=ratios,
+                                     use_kernels=use_kernels,
+                                     tile_elems=tile_elems)
+        if ratios is not None:
+            from repro.kernels import ref
+            assert scale is None
+            scale = ref.expand_ratios(ratios, pool.sizes, pool.size)
+        new_master, new_state = update_pool(name, master, grads, state,
+                                            mask, cfg, lr, scale=scale,
+                                            use_kernels=use_kernels)
+        return pool.unravel(new_master), new_state
 
 
 def update_view(name: str, view, master, grads, state, mask, cfg, lr, *,

@@ -177,9 +177,11 @@ def reduce_pool(x: jax.Array, axes: Sequence[str],
     docs/collectives.md. Note that algorithms need not bottom out in a
     psum at all: ``pallas_ring`` executes its own 2(N-1)-step neighbor
     exchange (``repro.kernels.ring_reduce`` on TPU, the ``lax.ppermute``
-    twin in ``repro.kernels.ref`` elsewhere).
+    twin in ``repro.kernels.ref`` elsewhere). Scope ``gf.reduce``, that
+    of GradientFlow's data-parallel exchange.
     """
     axes = tuple(axes)
-    if algo is None:
-        return jax.lax.psum(x, axes)
-    return algo.reduce(x, axes)
+    with jax.named_scope("gf.reduce"):
+        if algo is None:
+            return jax.lax.psum(x, axes)
+        return algo.reduce(x, axes)
