@@ -29,7 +29,7 @@ Every trainer setting runs the same steps through the jnp twins
 are finite and match the twins' at every step, that the state after step
 1 matches within the stated limits (and that those limits reject no
 update and, on four chips, 1/4 of the update), and that
-``ops.dispatch_counts`` holds only ``.kernel`` entries. Step times
+``ops.dispatch_counts`` holds no ``.ref`` entry. Step times
 printed here are a smoke, not a benchmark.
 
 The script runs in one process and starts none. It exits non-zero, and
@@ -394,18 +394,18 @@ def supervised_run(setting: Setting) -> None:
 
 
 def check_dispatch(expect: set) -> dict:
-    """Every kernel dispatch of this process (they happen while a window
-    is traced, i.e. compiled) took the ``.kernel`` path, and each kernel
-    in ``expect`` was dispatched."""
+    """No dispatch of this process (they happen while a window is
+    traced, i.e. compiled) took a ``.ref`` twin, and each path in
+    ``expect`` (``<op>.kernel``, or ``pool_pack.xla`` for the no-census
+    pack) was dispatched."""
     from repro.kernels import ops
     counts = dict(ops.dispatch_counts)
-    refs = sorted(k for k in counts if not k.endswith(".kernel"))
+    refs = sorted(k for k in counts if k.endswith(".ref"))
     if refs:
-        fail(f"non-kernel dispatch on the trainer path: {refs} ({counts})")
-    missing = sorted(f"{k}.kernel" for k in expect
-                     if f"{k}.kernel" not in counts)
+        fail(f"ref-twin dispatch on the trainer path: {refs} ({counts})")
+    missing = sorted(k for k in expect if k not in counts)
     if missing:
-        fail(f"kernels never dispatched: {missing} ({counts})")
+        fail(f"paths never dispatched: {missing} ({counts})")
     return counts
 
 
@@ -486,11 +486,11 @@ def bucket_check(mesh, elems: int) -> None:
 
 
 def kernel_exactness(setting: Setting) -> None:
-    """The pack and the per-bucket update kernels, compiled for the chip
-    at the trainer's pool layout, against their jnp twins on the same
-    random inputs: the pack (a copy and a cast) bit for bit, the update
-    (the same elementwise f32 math, fused differently) to 2**-20 of each
-    output's largest value."""
+    """The pack (the no-census XLA pass) and the per-bucket update
+    kernels, compiled for the chip at the trainer's pool layout, against
+    their jnp twins on the same random inputs: the pack (a copy and a
+    cast) bit for bit, the update (the same elementwise f32 math, fused
+    differently) to 2**-20 of each output's largest value."""
     import jax.numpy as jnp
     import numpy as np
     from repro.kernels import ops, ref
@@ -544,7 +544,8 @@ def one_chip(devices) -> None:
         limit, BATCH_MIN, BATCH_MAX)
     log(f"  -> global batch {batch} (seq {SEQ_LEN})")
     kernel_exactness(lazy)
-    run_setting(lazy, twin_for(lazy, twin), {"pool_pack", "update_unpack"})
+    run_setting(lazy, twin_for(lazy, twin),
+                {"pool_pack.xla", "update_unpack.kernel"})
     del lazy, twin
     log(f"[fit] csc at global batch {batch}, or the largest below it "
         f"whose compiled windows fit:")
@@ -552,8 +553,8 @@ def one_chip(devices) -> None:
         lambda b: pair("csc", "csc", b, "1x1"), batch, limit, BATCH_MIN,
         batch)
     run_setting(csc, twin_for(csc, twin),
-                {"pool_pack", "update_unpack", "csc_compact",
-                 "chunk_l1norm"})
+                {"pool_pack.xla", "update_unpack.kernel",
+                 "csc_compact.kernel", "chunk_l1norm.kernel"})
 
 
 def four_chips(devices) -> None:
@@ -582,12 +583,12 @@ def four_chips(devices) -> None:
               PER_CHIP_BATCH, PER_CHIP_BATCH)
     result = twin_for(ring, twin)
     del twin
-    run_setting(ring, result, {"pool_pack", "update_unpack",
-                               "ring_allreduce"})
+    run_setting(ring, result, {"pool_pack.xla", "update_unpack.kernel",
+                               "ring_allreduce.kernel"})
     del ring
     flat = Setting("lazy flat x4", train_argv("lazy", 4 * PER_CHIP_BATCH,
                                               "4x1", kernels=True))
-    run_setting(flat, result, {"pool_pack", "update_unpack"})
+    run_setting(flat, result, {"pool_pack.xla", "update_unpack.kernel"})
 
 
 def main(argv=None) -> int:

@@ -11,10 +11,10 @@ In JAX the analogue is a deterministic ravel of the gradient pytree into a
 embedding → layers → head; backward generates head-first), plus metadata
 (offsets / sizes / names) so that:
 
-  * ``pack`` builds the pool in a single pass with zero concatenates
-    (static-offset in-place writes + one trailing wire cast + optional
-    fused chunk-L1 census; ``pack_into`` threads a donated staging buffer
-    so steady-state steps allocate nothing pool-sized);
+  * ``pack`` builds the pool in a single pass (cast, place at the static
+    offsets, optional fused chunk-L1 census; ``pack_into`` threads a
+    donated staging buffer so steady-state steps allocate nothing
+    pool-sized);
 
   * lazy allreduce can split the pool into θ-element buckets whose psum
     depends only on the grads inside the bucket (XLA can then overlap each
@@ -163,17 +163,19 @@ class GradientPool:
         """Pytree → (1-D pool, optional f32 per-chunk L1 norms), one pass.
 
         Fuses what used to be three passes — concatenate-ravel, wire-dtype
-        cast, chunk-norm census — into a single sweep with no concatenate:
+        cast, chunk-norm census — into a single sweep. With kernels off
         each leaf is written into its static segment of one preallocated
         buffer, with a single trailing cast to ``dtype``. ``norms_chunk >
         0`` additionally emits the per-chunk L1 norms of the packed (wire)
         values. ``out`` optionally supplies the staging buffer (see
         ``pack_into`` for the donation-threading variant that returns it).
 
-        ``use_kernels=True`` routes through the streaming tiled Pallas
-        kernel at EVERY pool size: leaf slices DMA through ~512KiB VMEM
-        tiles (``tile_elems`` overrides the auto tile), so peak on-chip
-        residency is O(tile) rather than O(pool)."""
+        ``use_kernels=True`` routes through ``kernels.ops.pool_pack``: a
+        pack with no census is one fused XLA pass (convert and
+        concatenate, no pool-sized buffer in the leaves' dtype); a census
+        pack runs the streaming tiled Pallas kernel at every pool size,
+        leaf slices DMA'd through ~512KiB VMEM tiles (``tile_elems``
+        overrides the auto tile)."""
         pool, norms, _ = self._pack(grads, dtype, norms_chunk, use_kernels,
                                     out, tile_elems)
         return pool, norms
@@ -192,10 +194,11 @@ class GradientPool:
 
         * leaves' (source) dtype — the ref path stages in place and casts
           to ``dtype`` in one trailing pass (the original contract);
-        * wire dtype with ``use_kernels=True`` — the streaming pack kernel
-          aliases ``out`` to its pool output (``input_output_aliases``),
-          so the returned pool IS the staging for the next step: one
-          wire-dtype buffer, re-written fully in place every pack.
+        * wire dtype with ``use_kernels=True`` — ``out`` is donated to the
+          pack (the census kernel aliases it to its pool output through
+          ``input_output_aliases``; the no-census XLA pass takes it as a
+          donated argument), so the returned pool IS the staging for the
+          next step: one wire-dtype buffer, re-written every pack.
         """
         return self._pack(grads, dtype, norms_chunk, use_kernels, out,
                           tile_elems)

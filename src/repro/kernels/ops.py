@@ -15,6 +15,7 @@ each call took.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -138,24 +139,38 @@ def pool_pack(leaves: Sequence[jax.Array], offsets: Tuple[int, ...],
     Returns (wire pool, norms or None, staging buffer or None) — see
     ref.pool_pack for the staging/donation contract.
 
-    Dispatches to the streaming tiled kernel at EVERY pool size (peak
-    VMEM is O(tile); the old 4M-element whole-pool bound is retired).
+    ``chunk_elems`` decides the path:
 
-    Donated staging: a **wire-dtype** ``out`` buffer rides through the
-    kernel as an ``input_output_aliases`` operand — the packed pool is
-    written into the donated buffer and returned as the staging for the
-    next step, so steady-state packs allocate nothing pool-sized. A
-    *source*-dtype ``out`` that differs from the wire dtype asks for the
-    ref twin's stage-then-cast contract and takes the ref twin (the
-    trainer never passes one with kernels on: its staging is always the
-    wire dtype), as do empty pools and the shard_map/interpret vma
-    limitation described in the module docstring."""
+    * no census (``chunk_elems == 0``) — one XLA pass on every backend
+      (``_xla_pack``, counted ``pool_pack.xla``): each leaf converted to
+      the wire dtype and concatenated at its offset with the zero tail.
+      XLA fuses that into one bandwidth-bound write of the pool; the
+      Mosaic kernel would add nothing but its O(tiles) unrolled schedule;
+    * census (quantized wires) — the streaming tiled kernel, which folds
+      the chunk-L1 norms into the same pass (peak VMEM O(tile) at every
+      pool size).
+
+    Donated staging: a **wire-dtype** ``out`` buffer is consumed — the
+    kernel aliases it to its pool output (``input_output_aliases``), the
+    XLA pass takes it as a donated argument — and the packed pool is
+    returned as the staging for the next step, so steady-state packs
+    allocate nothing pool-sized. A *source*-dtype ``out`` that differs
+    from the wire dtype asks for the ref twin's stage-then-cast contract
+    and takes the ref twin (the trainer never passes one with kernels
+    on: its staging is always the wire dtype), as do empty pools and, for
+    the census kernel, the shard_map/interpret vma limitation described
+    in the module docstring."""
     wire = jnp.dtype(wire_dtype)
     src = jnp.result_type(*leaves) if leaves else wire
     wire_staging = out is not None and out.dtype == wire
     assert out is None or wire_staging or out.dtype == src, (
         "staging buffer must be wire- or source-dtype",
         out.dtype, wire, src)
+    if leaves and (out is None or wire_staging) and chunk_elems == 0:
+        _count("pool_pack", "xla")
+        pool = _xla_pack(tuple(leaves), tuple(offsets), pool_size,
+                         wire.name, staging=out)
+        return pool, None, (pool if wire_staging else None)
     if not leaves or _needs_ref_fallback(*leaves) or \
             (out is not None and not wire_staging):
         _count("pool_pack", "ref")
@@ -174,6 +189,35 @@ def pool_pack(leaves: Sequence[jax.Array], offsets: Tuple[int, ...],
         chunk_elems, wire.name, tile_elems=tile_elems,
         staging=out, interpret=_interpret())
     return pool, norms, (pool if wire_staging else None)
+
+
+@functools.partial(jax.jit, static_argnames=("offsets", "pool_size",
+                                              "wire_dtype"),
+                   donate_argnames=("staging",), keep_unused=True)
+def _xla_pack(leaves: Tuple[jax.Array, ...], offsets: Tuple[int, ...],
+              pool_size: int, wire_dtype: str,
+              staging: Optional[jax.Array] = None) -> jax.Array:
+    """The no-census pack as one XLA pass: per-leaf convert, concatenate,
+    zero tail up to ``pool_size``. No pool-sized buffer exists in the
+    source dtype, only the wire-dtype pool. ``staging`` is read by
+    nothing: donating it lets XLA write the pool into its buffer (kept,
+    not pruned, for that; the TPU compiler turns the concatenate into
+    in-place writes of each converted leaf there). Each leaf goes
+    through the staging dtype first, as in ``ref.pool_pack``, so
+    mixed-dtype trees round the same way; for float leaves that widening
+    is exact."""
+    del staging
+    wire = jnp.dtype(wire_dtype)
+    src = jnp.result_type(*leaves)
+    parts, end = [], 0
+    for x, off in zip(leaves, offsets):
+        assert off == end, ("leaves must tile the pool in order", off, end)
+        parts.append(x.astype(src).astype(wire))
+        end += x.shape[0]
+    assert end <= pool_size, (end, pool_size)
+    if end < pool_size:
+        parts.append(jnp.zeros((pool_size - end,), wire))
+    return jnp.concatenate(parts)
 
 
 def update_unpack(master, grads, momentum_buf, mask,

@@ -5,10 +5,12 @@ of the output pool, and each grid step DMAs exactly the leaf slices that
 land in its tile from HBM into a double-buffered VMEM scratch slot, casts
 them to the wire dtype on the way out, and reduces the tile's chunk-L1
 census from the same resident data. Peak VMEM is O(tile), independent of
-pool size — this retires the whole-pool-in-VMEM variant (and its 4M-element
-ref fallback in ``ops.py``): the streaming kernel is the production path at
-every pool size; the jnp twin in ``ref.py`` remains as the correctness
-oracle and the shard_map/interpret fallback only.
+pool size. ``ops.pool_pack`` runs it for packs with a census (quantized
+wires), at every pool size; a pack with no census is one fused XLA pass
+there (``ops._xla_pack``), since the kernel's only gain over XLA is the
+census it folds into the pass. Its no-census mode stays as a test oracle.
+The jnp twin in ``ref.py`` remains as the correctness oracle and the
+shard_map/interpret fallback only.
 
 Mechanics (see ``tiling.py`` for the schedule):
 

@@ -350,9 +350,11 @@ class Trainer:
 
     @property
     def _staging_dtype(self):
-        """Dtype of the pack staging buffer (``pool.pack_into``): the
-        wire dtype when the streaming kernel aliases the pool to its
-        staging, else the leaves' (f32) source dtype — the ref twin's
+        """Dtype of the pack staging buffer (``pool.pack_into``): with
+        kernels on, the pack dtype — ``ops.pool_pack`` consumes a staging
+        buffer of that dtype and hands the pool back as the next one,
+        whether the no-census XLA pass or the census kernel packs it;
+        with kernels off, the leaves' (f32) source dtype — the ref twin's
         stage-then-cast contract."""
         pd = self._pack_dtype
         if pd == jnp.dtype(jnp.float32) or self.gf_cfg.use_kernels:

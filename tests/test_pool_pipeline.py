@@ -260,6 +260,104 @@ def test_kernel_pack_into_source_dtype_staging_still_routes_to_ref():
         before.get("pool_pack.ref", 0)
 
 
+def _mixed_tree(seed=0):
+    tree = make_tree(seed=seed, sizes=BIG_SIZES)
+    tree["t1"] = tree["t1"].astype(jnp.bfloat16)
+    return tree
+
+
+# No-census packs: (tree, wire dtype, pad_to, donated wire staging).
+NO_CENSUS_CASES = {
+    "f32_to_bf16_donated": (make_tree, jnp.bfloat16, CHUNK, True),
+    "f32_to_f32_no_staging": (make_tree, jnp.float32, CHUNK, False),
+    "mixed_dtype_tree": (_mixed_tree, jnp.bfloat16, CHUNK, False),
+    "sizes_not_1024_multiples": (
+        lambda seed=0: make_tree(seed=seed, sizes=BIG_SIZES),
+        jnp.bfloat16, 1, True),
+    "pad_to_tail": (lambda seed=0: make_tree(seed=seed, sizes=BIG_SIZES),
+                    jnp.float32, 1000, True),
+}
+
+
+@pytest.mark.parametrize("entry", ["ops", "pool"])
+@pytest.mark.parametrize("case", sorted(NO_CENSUS_CASES))
+def test_no_census_pack_takes_xla_pass_bit_equal(case, entry):
+    """A pack that asks for no census is one XLA pass
+    (``pool_pack.xla``), never the Mosaic kernel, and its pool is bit
+    for bit the ref twin's and the kernel's (interpret mode). A donated
+    wire-dtype staging buffer is consumed and the pool comes back as the
+    next step's staging."""
+    make, wire, pad_to, staged = NO_CENSUS_CASES[case]
+    tree = make(seed=4)
+    pool = GradientPool(tree, pad_to=pad_to)
+    leaves = pool.flat_leaves(tree)
+    want, _, _ = ref.pool_pack(leaves, pool.offsets, pool.size, 0, wire)
+    kern, _ = k_pack(tuple(leaves), pool.offsets, pool.sizes, pool.size, 0,
+                     jnp.dtype(wire).name, interpret=True)
+    staging = jnp.zeros((pool.size,), wire) if staged else None
+    before = dict(ops.dispatch_counts)
+    if entry == "ops":
+        got, norms, staging_out = ops.pool_pack(
+            leaves, pool.offsets, pool.sizes, pool.size, 0, wire,
+            out=staging)
+    elif staged:
+        got, norms, staging_out = pool.pack_into(
+            staging, tree, dtype=wire, use_kernels=True)
+    else:
+        got, norms = pool.pack(tree, dtype=wire, use_kernels=True)
+        staging_out = None
+    assert ops.dispatch_counts.get("pool_pack.xla", 0) == \
+        before.get("pool_pack.xla", 0) + 1
+    assert ops.dispatch_counts.get("pool_pack.kernel", 0) == \
+        before.get("pool_pack.kernel", 0)
+    assert norms is None and got.dtype == jnp.dtype(wire)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(kern))
+    if staged:
+        assert staging.is_deleted()
+        assert staging_out is got
+
+
+@pytest.mark.parametrize("entry", ["ops", "pool"])
+def test_census_pack_still_takes_kernel(entry):
+    tree = make_tree()
+    pool = GradientPool(tree, pad_to=CHUNK)
+    before = dict(ops.dispatch_counts)
+    if entry == "ops":
+        ops.pool_pack(pool.flat_leaves(tree), pool.offsets, pool.sizes,
+                      pool.size, CHUNK, jnp.bfloat16)
+    else:
+        pool.pack(tree, dtype=jnp.bfloat16, norms_chunk=CHUNK,
+                  use_kernels=True)
+    assert ops.dispatch_counts.get("pool_pack.kernel", 0) == \
+        before.get("pool_pack.kernel", 0) + 1
+    assert ops.dispatch_counts.get("pool_pack.xla", 0) == \
+        before.get("pool_pack.xla", 0)
+
+
+@pytest.mark.parametrize("chunk", [0, CHUNK], ids=["no_census", "census"])
+def test_pack_lowered_for_tpu_holds_kernel_only_with_census(chunk,
+                                                            monkeypatch):
+    """Lowered for a TPU (ops told it runs compiled), an f32 -> bf16 pack
+    holds a Mosaic call (``tpu_custom_call``) only where a census is
+    asked, and the no-census pass makes no f32 buffer of the pool's
+    size (the ref twin's stage-in-f32-then-cast would)."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    pool = GradientPool(make_tree(sizes=BIG_SIZES), pad_to=CHUNK)
+    leaves = [jax.ShapeDtypeStruct((n,), jnp.float32) for n in pool.sizes]
+
+    def pack(*ls):
+        return ops.pool_pack(list(ls), pool.offsets, pool.sizes, pool.size,
+                             chunk, jnp.bfloat16)[0]
+
+    txt = jax.jit(pack).trace(*leaves).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert ("tpu_custom_call" in txt) == (chunk > 0)
+    if not chunk:
+        assert f"tensor<{pool.size}xf32>" not in txt
+        assert f"tensor<{pool.size}xbf16>" in txt
+
+
 def test_pack_mixed_dtype_tree_promotes_like_concatenate():
     """Regression: a pytree with mixed leaf dtypes must pack (per-leaf
     promotion to the staging dtype), as the old concatenate-ravel did."""
